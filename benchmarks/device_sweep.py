@@ -334,7 +334,8 @@ def run_subprocess(quick: bool = False, timeout: int = 1800,
         cmd.append("--quick")
     if kwargs:
         cmd += ["--config", json.dumps(kwargs)]
-    env = {**os.environ,
+    # The sweep models forced host devices: keep the child off the chip.
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
            "PYTHONPATH": os.pathsep.join(
                p for p in (str(REPO_ROOT / "src"),
                            os.environ.get("PYTHONPATH")) if p)}

@@ -121,8 +121,7 @@ def fused_iteration_case(src, dst, params: ICPParams | None = None,
     nn_brute = resident_nn_fn(dstj)
 
     def unfused_pallas_iter(s):
-        d2, idx = nn_brute(s)
-        matched = jnp.take(dstj, idx, axis=0)
+        d2, _, matched = nn_brute(s)
         w = (d2 <= gate2).astype(jnp.float32)
         return estimate_rigid_transform(s, matched, w)
 

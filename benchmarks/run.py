@@ -39,6 +39,7 @@ from benchmarks import (convergence, device_sweep, kernel_resources,
                         registration_throughput, robustness,
                         roofline_report, service_throughput)
 from benchmarks.common import QUICK_SCENE, emit
+from repro.compile_cache import enable_compile_cache
 
 SUITES = {
     "table3": registration_accuracy.run,
@@ -80,6 +81,7 @@ def main(argv=None) -> None:
     ap.add_argument("--quick", action="store_true",
                     help="smoke mode: reduced scenes / 2 frames per suite")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     failed = []
     for name, fn in SUITES.items():
         if args.only and name != args.only:

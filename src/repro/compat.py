@@ -1,54 +1,15 @@
-"""Version-compatibility shims for jax APIs that moved or were renamed.
+"""Mesh construction pinned to the sharding mode this repo is written for.
 
-The repo targets current jax but must run on the container's older
-runtime; every cross-version touchpoint lives here so call sites stay
-written against the modern spelling:
-
-  * ``shard_map``      — ``jax.shard_map`` (public from 0.8) vs
-                         ``jax.experimental.shard_map`` whose kwarg is
-                         ``check_rep`` instead of ``check_vma``.
-  * ``make_mesh``      — ``axis_types=`` only exists on newer jax.
-  * ``axis_size``      — ``jax.lax.axis_size`` is new; ``psum(1, ax)``
-                         is the portable spelling.
-  * ``cost_analysis``  — ``compiled.cost_analysis()`` returns a dict on
-                         new jax, a one-element list of dicts before.
+``jax.make_mesh`` gives ``Explicit`` axes by default, under which
+``res.T[i]`` on a sharded result raises ``ShardingTypeError``. Every mesh
+the code and its multi-device tests build is an ``Auto`` mesh, made here.
 """
 from __future__ import annotations
 
 import jax
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - jax < 0.8
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-        if check_vma is not None:
-            kwargs["check_rep"] = check_vma
-        return _shard_map_experimental(f, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs, **kwargs)
-
 
 def make_mesh(shape, axes):
-    """jax.make_mesh, with Auto axis_types where the API supports them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def axis_size(ax: str):
-    """Size of a named mapped axis, inside shard_map/vmap."""
-    try:
-        return jax.lax.axis_size(ax)
-    except AttributeError:  # pragma: no cover - jax < 0.6
-        return jax.lax.psum(1, ax)
-
-
-def cost_analysis(compiled) -> dict:
-    """Normalise compiled.cost_analysis() to a dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``jax.make_mesh`` with ``Auto`` axis types on every axis."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -42,7 +42,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import axis_size as _axis_size, shard_map
 from repro.core.icp import ICPParams, ICPResult, icp, icp_fixed_iterations
 from repro.core.nn_search import nn_search
 
@@ -107,8 +106,8 @@ def stream_sharded_icp(mesh: Mesh, src_b: jax.Array, dst_b: jax.Array,
     spec = P("streams")
     out_specs = ICPResult(T=spec, rmse=spec, iterations=spec,
                           converged=spec, inlier_frac=spec, degenerate=spec)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec,) * 5,
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 5,
+                       out_specs=out_specs, check_vma=False)
     return fn(src_b, dst_b, initial_transforms, src_valid, dst_valid)
 
 
@@ -159,7 +158,7 @@ def distributed_nn_search(mesh: Mesh, src: jax.Array, dst: jax.Array,
         stride = m_local
         for ax in reversed(axes):
             offset = offset + jax.lax.axis_index(ax).astype(jnp.int32) * stride
-            stride = stride * _axis_size(ax)
+            stride = stride * jax.lax.axis_size(ax)
         cand = jnp.concatenate(
             [d2[:, None], (idx_local + offset)[:, None].astype(d2.dtype)], axis=1)
         for ax in axes:
@@ -168,9 +167,9 @@ def distributed_nn_search(mesh: Mesh, src: jax.Array, dst: jax.Array,
             cand = jnp.take_along_axis(g, win[None, :, None], axis=0)[0]
         return cand[:, 0], cand[:, 1].astype(jnp.int32)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(), P(axes)),
-                   out_specs=(P(), P()), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(), P(axes)),
+                       out_specs=(P(), P()), check_vma=False)
     return fn(src, dst)
 
 
@@ -203,11 +202,11 @@ def icp_sharded(mesh: Mesh, source: jax.Array, target: jax.Array,
     out_specs = ICPResult(T=P(), rmse=P(), iterations=P(), converged=P(),
                           inlier_frac=P(), degenerate=P())
     if dst_normals is None:
-        fn = shard_map(body, mesh=mesh, in_specs=(P(), P(axes)),
-                       out_specs=out_specs, check_vma=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P(axes)),
+                           out_specs=out_specs, check_vma=False)
         return fn(source, target)
-    fn = shard_map(body, mesh=mesh, in_specs=(P(), P(axes), P(axes)),
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P(axes), P(axes)),
+                       out_specs=out_specs, check_vma=False)
     return fn(source, target, dst_normals)
 
 
@@ -264,14 +263,14 @@ def batched_icp_sharded(mesh: Mesh, src_batch: jax.Array,
                           converged=P(f_axes), inlier_frac=P(f_axes),
                           degenerate=P(f_axes))
     if dst_normals is None:
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(P(f_axes), P(f_axes, t_axes), P(f_axes)),
-                       out_specs=out_specs, check_vma=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(f_axes), P(f_axes, t_axes), P(f_axes)),
+                           out_specs=out_specs, check_vma=False)
         return fn(src_batch, dst_batch, src_valid)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(f_axes), P(f_axes, t_axes), P(f_axes),
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(f_axes), P(f_axes, t_axes), P(f_axes),
                              P(f_axes, t_axes)),
-                   out_specs=out_specs, check_vma=False)
+                       out_specs=out_specs, check_vma=False)
     return fn(src_batch, dst_batch, src_valid, dst_normals)
 
 

@@ -152,7 +152,8 @@ def _icp_iteration(source, state: ICPState, params: ICPParams,
     else:
         T_step = tf.estimate_rigid_transform(src_t, matched, weights)
     T_delta = jnp.where(degenerate, jnp.eye(4, dtype=source.dtype), T_step)
-    T_new = T_delta @ state.T  # cumulative product, paper eq. (3)
+    # cumulative product, paper eq. (3)
+    T_new = jnp.matmul(T_delta, state.T, precision="highest")
     delta = tf.transform_delta(T_delta)
     err = jnp.where(degenerate, jnp.asarray(jnp.inf, source.dtype),
                     tf.rmse(tf.transform_points(T_delta, src_t), matched,
@@ -190,7 +191,7 @@ def _fused_icp_iteration(source, state: ICPState, params: ICPParams,
         T_step = tf.estimate_from_moments(m.sw, m.sp, m.sq,
                                           m.spq).astype(source.dtype)
     T_delta = jnp.where(degenerate, jnp.eye(4, dtype=source.dtype), T_step)
-    T_new = T_delta @ state.T
+    T_new = jnp.matmul(T_delta, state.T, precision="highest")
     delta = tf.transform_delta(T_delta)
     err = jnp.where(degenerate, jnp.asarray(jnp.inf, source.dtype),
                     tf.rmse_from_moments(T_delta, m.sw, m.sp, m.sq, m.spq,

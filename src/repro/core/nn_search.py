@@ -18,7 +18,12 @@ Two implementations:
     BlockSpec tiling and a fused transform prologue (validated in interpret
     mode against ``repro.kernels.ref``).
 
-Both return (min_dist_sq, argmin_index) exactly.
+Both return (min_dist_sq, argmin_index) exactly. The expansion's
+cancellation at scene scale (terms of ~3000 m² cancelling to ~1e-3 m²)
+costs about 1e-3 m² of absolute error, enough to swap near-tied
+neighbours, so both searchers keep the two best candidates by expansion
+score and pick between them on the directly computed distance
+(:func:`merge_two_best`, :func:`closer_of_two`).
 """
 from __future__ import annotations
 
@@ -33,9 +38,40 @@ def pairwise_sq_dists(src: jax.Array, dst: jax.Array) -> jax.Array:
     # fp32 accumulation: metric data; see DESIGN.md §2 (precision note).
     sn = jnp.sum(src * src, axis=-1, keepdims=True)          # (N,1)
     dn = jnp.sum(dst * dst, axis=-1, keepdims=True).T        # (1,M)
-    cross = src @ dst.T                                       # MXU
+    cross = jnp.matmul(src, dst.T, precision="highest")                # MXU
     d2 = sn + dn - 2.0 * cross
     return jnp.maximum(d2, 0.0)  # clamp fp roundoff
+
+
+def merge_two_best(best, new):
+    """Running two-best merge: ``best`` and ``new`` are ``(d1, i1, d2,
+    i2)`` score/index pairs with ``d1 <= d2``. Returns the two smallest of
+    the four; on equal scores the running (earlier) entry wins, matching
+    the first-match argmin."""
+    b1, j1, b2, j2 = best
+    v1, i1, v2, i2 = new
+    lead = v1 < b1
+    alt_d = jnp.where(lead, jnp.where(v2 < b1, v2, b1),
+                      jnp.where(v1 < b2, v1, b2))
+    alt_i = jnp.where(lead, jnp.where(v2 < b1, i2, j1),
+                      jnp.where(v1 < b2, i1, j2))
+    return (jnp.where(lead, v1, b1), jnp.where(lead, i1, j1), alt_d, alt_i)
+
+
+def closer_of_two(src, dst, i1, i2, has_second):
+    """Of each query's two candidates ``dst[i1]`` and ``dst[i2]`` (the
+    second only where ``has_second``), the one nearer by the directly
+    computed distance; ties keep ``i1``. Returns ``(d2, idx, points)``."""
+    def direct(i):
+        pts = jnp.take(dst, i, axis=0)
+        diff = src - pts
+        return jnp.sum(diff * diff, axis=-1).astype(jnp.float32), pts
+
+    e1, p1 = direct(i1)
+    e2, p2 = direct(i2)
+    use2 = jnp.logical_and(has_second, e2 < e1)
+    return (jnp.where(use2, e2, e1), jnp.where(use2, i2, i1),
+            jnp.where(use2[:, None], p2, p1))
 
 
 def nn_search(src: jax.Array, dst: jax.Array, *, chunk: int = 2048,
@@ -89,7 +125,6 @@ def nn_search(src: jax.Array, dst: jax.Array, *, chunk: int = 2048,
     src_c = src.astype(jnp.bfloat16) if lowp else src
 
     def body(carry, xs):
-        best_d2, best_idx = carry
         if valid_chunks is None:
             dchunk, base = xs
             valid = None
@@ -106,35 +141,39 @@ def nn_search(src: jax.Array, dst: jax.Array, *, chunk: int = 2048,
             d2 = (sn.astype(jnp.bfloat16)[:, None]
                   + dn.astype(jnp.bfloat16)[None, :] - 2.0 * cross)
         else:
-            cross = src @ dchunk.T                             # (N, chunk) MXU
+            cross = jnp.matmul(src, dchunk.T, precision="highest")  # (N, chunk) MXU
             d2 = sn[:, None] + dn[None, :] - 2.0 * cross
         if valid is not None:
             d2 = jnp.where(valid[None, :], d2, jnp.asarray(jnp.inf, d2.dtype))
-        local_idx = jnp.argmin(d2, axis=1)
-        local_d2 = jnp.take_along_axis(d2, local_idx[:, None],
-                                       axis=1)[:, 0].astype(jnp.float32)
-        improved = local_d2 < best_d2
-        best_d2 = jnp.where(improved, local_d2, best_d2)
-        best_idx = jnp.where(improved, base + local_idx.astype(jnp.int32), best_idx)
-        return (best_d2, best_idx), None
+        i1 = jnp.argmin(d2, axis=1)
+        cols = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
+        rest = jnp.where(cols == i1[:, None], jnp.asarray(jnp.inf, d2.dtype),
+                         d2)
+        i2 = jnp.argmin(rest, axis=1)
 
-    # Carry pinned to fp32: local_d2 is always cast to fp32, so an
+        def score(tile, i):
+            return jnp.take_along_axis(tile, i[:, None],
+                                       axis=1)[:, 0].astype(jnp.float32)
+
+        new = (score(d2, i1), base + i1.astype(jnp.int32),
+               score(rest, i2), base + i2.astype(jnp.int32))
+        return merge_two_best(carry, new), None
+
+    # Carry pinned to fp32: scores are always cast to fp32, so an
     # src.dtype carry would silently upcast (or mis-compare) for bf16
     # callers.
-    init = (jnp.full((n,), jnp.inf, dtype=jnp.float32),
-            jnp.zeros((n,), dtype=jnp.int32))
+    inf = jnp.full((n,), jnp.inf, dtype=jnp.float32)
+    zero = jnp.zeros((n,), dtype=jnp.int32)
+    init = (inf, zero, inf, zero)
     bases = jnp.arange(n_chunks, dtype=jnp.int32) * chunk
     xs = ((dst_chunks, bases) if valid_chunks is None
           else (dst_chunks, bases, valid_chunks))
-    (best_d2, best_idx), _ = jax.lax.scan(body, init, xs)
-    # The expansion picks the right argmin but its cancellation
-    # (sn + dn - 2·cross at scene scale) costs ~1e-4 absolute in the
-    # distances; recompute the O(N) winner distances directly so the
-    # returned d2 is exact. Keep inf where nothing was valid.
-    matched = jnp.take(dst, best_idx, axis=0)
-    diff = src - matched
-    exact = jnp.sum(diff * diff, axis=-1).astype(jnp.float32)
-    best_d2 = jnp.where(jnp.isinf(best_d2), best_d2, exact)
+    (score1, idx1, score2, idx2), _ = jax.lax.scan(body, init, xs)
+    # Pick between the two best by the direct distance, which is also the
+    # returned d2 (exact). Keep inf where nothing was valid.
+    exact, best_idx, matched = closer_of_two(src, dst, idx1, idx2,
+                                             jnp.isfinite(score2))
+    best_d2 = jnp.where(jnp.isinf(score1), score1, exact)
     if return_points:
         return jnp.maximum(best_d2, 0.0), best_idx, matched
     return jnp.maximum(best_d2, 0.0), best_idx

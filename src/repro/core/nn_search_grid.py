@@ -83,19 +83,10 @@ def _neighbor_offsets(rings: int) -> tuple:
 _MASK_COORD = 1.0e15
 
 
-def gather_candidates(src: jax.Array, grid: VoxelGrid, max_per_cell: int,
-                      rings: int = 1):
-    """Gather each query's (2·rings+1)³-neighbourhood candidate set.
-
-    Returns ``(cand_pts, cand_idx, cand_valid)`` with shapes
-    ((N, C*K, 3), (N, C*K), (N, C*K)) for C = (2·rings+1)³; masked slots
-    carry far-sentinel coordinates so consumers may skip the mask in the
-    distance argmin. ``cand_idx`` is in the *original* target ordering.
-    ``rings`` trades cell occupancy against neighbourhood width: the
-    guaranteed-exact radius is ``rings * voxel_size``, so rings=2 with a
-    half-size voxel covers the same radius with ~4x fewer points per cell
-    (useful against ``max_per_cell`` overflow on dense surfaces).
-    """
+def _candidate_slots(src: jax.Array, grid: VoxelGrid, max_per_cell: int,
+                     rings: int):
+    """Rows of ``grid.points`` holding each query's candidates, and which
+    of the ``(N, C*K)`` slots are real: see :func:`gather_candidates`."""
     dims = grid.dims
     # clip=False: a query outside the lattice keeps its true out-of-range
     # cell, so its neighbourhood window only picks up lattice cells it
@@ -120,10 +111,39 @@ def gather_candidates(src: jax.Array, grid: VoxelGrid, max_per_cell: int,
     ck = off.shape[0] * max_per_cell
     pos = pos.reshape(n, ck)
     cand_valid = cand_valid.reshape(n, ck)
+    return pos, cand_valid
+
+
+def gather_candidates(src: jax.Array, grid: VoxelGrid, max_per_cell: int,
+                      rings: int = 1):
+    """Gather each query's (2·rings+1)³-neighbourhood candidate set.
+
+    Returns ``(cand_pts, cand_idx, cand_valid)`` with shapes
+    ((N, C*K, 3), (N, C*K), (N, C*K)) for C = (2·rings+1)³; masked slots
+    carry far-sentinel coordinates so consumers may skip the mask in the
+    distance argmin. ``cand_idx`` is in the *original* target ordering.
+    ``rings`` trades cell occupancy against neighbourhood width: the
+    guaranteed-exact radius is ``rings * voxel_size``, so rings=2 with a
+    half-size voxel covers the same radius with ~4x fewer points per cell
+    (useful against ``max_per_cell`` overflow on dense surfaces).
+    """
+    pos, cand_valid = _candidate_slots(src, grid, max_per_cell, rings)
     cand_pts = jnp.where(cand_valid[..., None], grid.points[pos],
                          jnp.asarray(_MASK_COORD, jnp.float32))
-    cand_idx = grid.point_ids[pos]
-    return cand_pts, cand_idx, cand_valid
+    return cand_pts, grid.point_ids[pos], cand_valid
+
+
+def gather_candidate_planes(src: jax.Array, grid: VoxelGrid,
+                            max_per_cell: int, rings: int = 1):
+    """:func:`gather_candidates` with the coordinates as three ``(N, C*K)``
+    planes. On a TPU an ``(N, C*K, 3)`` array is laid out with its 3-wide
+    minor dimension padded to 128 lanes, 40x its size; at the paper's
+    scan size that does not fit the chip."""
+    pos, cand_valid = _candidate_slots(src, grid, max_per_cell, rings)
+    mask = jnp.asarray(_MASK_COORD, jnp.float32)
+    planes = tuple(jnp.where(cand_valid, grid.points[:, a][pos], mask)
+                   for a in range(3))
+    return planes, grid.point_ids[pos], cand_valid
 
 
 def neighborhood_stats(src: jax.Array, grid: VoxelGrid,

@@ -68,9 +68,10 @@ from repro.core.health import (FAILED, OK, SUSPECT, HealthThresholds,
                                RegistrationHealth, assess_registration,
                                normal_equation_condition)
 from repro.core.icp import ICPParams, scrub_nonfinite
-from repro.core.transform import transform_points
+from repro.data.collate import PAD_SENTINEL
 from repro.data.normals import NormalParams, estimate_normals
-from repro.data.submap import Submap, SubmapParams
+from repro.data.submap import (Submap, SubmapParams, lane0_batch,
+                               outside_lattice_fracs)
 from repro.data.voxelize import voxel_downsample
 
 # Retry-tier pyramid schedules: ((voxel_m, iters, max_points), ...).
@@ -199,6 +200,22 @@ class FuseRequest(NamedTuple):
     pose: np.ndarray
 
 
+def prepare_scan(pts, valid, voxel: float, budget: int):
+    """Sensor-boundary stage of one frame: scrub NaN/Inf rows, then
+    voxel-downsample to at most ``budget`` points."""
+    pts, valid = scrub_nonfinite(pts, valid)
+    return voxel_downsample(pts, voxel, max_points=budget, valid=valid)
+
+
+@functools.partial(jax.jit, static_argnames=("voxel", "budget"))
+def prepare_scans(pts_b, valid_b, voxel: float, budget: int):
+    """:func:`prepare_scan` over ``(L, ...)`` lanes (see the lane note in
+    ``data/submap.py``). Returns ``(src_b, sv_b, n_valid_b)``."""
+    src_b, sv_b = jax.vmap(
+        lambda p, v: prepare_scan(p, v, voxel, budget))(pts_b, valid_b)
+    return src_b, sv_b, jnp.sum(sv_b, axis=1)
+
+
 @functools.partial(jax.jit, static_argnames=("nparams",))
 def _scan_plane_system(src: jax.Array, sv: jax.Array,
                        nparams: NormalParams) -> jax.Array:
@@ -208,7 +225,18 @@ def _scan_plane_system(src: jax.Array, sv: jax.Array,
     normals, nvalid = estimate_normals(src, nparams, valid=sv)
     w = jnp.logical_and(sv, nvalid).astype(jnp.float32)
     a = jnp.concatenate([jnp.cross(src, normals), normals], axis=-1)
-    return (a * w[:, None]).T @ a
+    return jnp.matmul((a * w[:, None]).T, a, precision="highest")
+
+
+def _compose(a, b) -> np.ndarray:
+    """``a @ b`` of 4x4 rigid transforms, in float64 on fresh copies,
+    rounded to float32. A float32 product of the same values can round
+    differently depending on where the operands sit in memory (the host
+    BLAS picks its kernel by alignment); the service hands its pipelines
+    views into one fleet-wide result buffer, so a float32 product would
+    break their bit-for-bit parity with a standalone pipeline."""
+    return (np.asarray(a, np.float64) @ np.asarray(b, np.float64)).astype(
+        np.float32)
 
 
 def _decay_toward_identity(T: np.ndarray, factor: float) -> np.ndarray:
@@ -257,6 +285,11 @@ class OdometryPipeline:
             # schedule; they don't apply to other engine constructors
             kwargs.pop("levels", None)
         self.engine = get_engine(config.engine, **kwargs)
+        # Per-frame device stages run at lane 0 of the engine's per-device
+        # slot block, so a pipeline on a service's slot engine runs the
+        # service's own executables (one lane for other engines).
+        self._lanes = getattr(self.engine, "lanes_per_device",
+                              getattr(self.engine, "slots", 1))
         # ``submap`` lets a fleet owner substitute a view over shared
         # device state (the sharded service's lane views) for the default
         # per-stream map; anything duck-typing Submap's read surface works.
@@ -274,20 +307,19 @@ class OdometryPipeline:
         """Constant-velocity pose prediction for the incoming frame."""
         if len(self.poses) < 2 or not self.config.motion_model:
             return self.poses[-1]
-        return (self.poses[-1] @ self._velocity).astype(np.float32)
+        return _compose(self.poses[-1], self._velocity)
 
     # -- health ------------------------------------------------------------
     def _out_of_lattice_frac(self, res, src, sv) -> float:
         """Fraction of the (pose-transformed) scan outside the submap
         lattice — the low-overlap/teleport signal. Pure bounds check
         against the rolling lattice; no grid build."""
-        p = self.submap.params
-        pts = transform_points(jnp.asarray(res.T, jnp.float32), src)
-        c = jnp.floor((pts - self.submap.origin) / p.voxel_size)
-        inb = jnp.all((c >= 0) & (c < jnp.asarray(p.dims, jnp.float32)),
-                      axis=-1)
-        n_valid = jnp.maximum(jnp.sum(sv), 1)
-        return float(jnp.sum(jnp.logical_and(sv, ~inb)) / n_valid)
+        L = self._lanes
+        frac = outside_lattice_fracs(
+            lane0_batch(jnp.asarray(res.T, jnp.float32), jnp.eye(4), L),
+            lane0_batch(src, PAD_SENTINEL, L), lane0_batch(sv, False, L),
+            lane0_batch(self.submap.origin, 0.0, L), self.submap.params)
+        return float(frac[0])
 
     def _assess(self, res, T0, src, sv, condition: float | None = None,
                 trust_prediction: bool = True,
@@ -414,13 +446,14 @@ class OdometryPipeline:
         cfg = self.config
         if downsampled is None:
             pts = jnp.asarray(scan, jnp.float32)
-            if valid is not None:
-                valid = jnp.asarray(valid, bool)
-            pts, valid = scrub_nonfinite(pts, valid)
-            src, sv = voxel_downsample(pts, cfg.scan_voxel,
-                                       max_points=cfg.scan_budget,
-                                       valid=valid)
-            n_valid = int(jnp.sum(sv))
+            valid = (jnp.ones(pts.shape[0], bool) if valid is None
+                     else jnp.asarray(valid, bool))
+            L = self._lanes
+            src_b, sv_b, nv_b = prepare_scans(
+                lane0_batch(pts, PAD_SENTINEL, L),
+                lane0_batch(valid, False, L), cfg.scan_voxel,
+                cfg.scan_budget)
+            src, sv, n_valid = src_b[0], sv_b[0], int(nv_b[0])
         else:
             src, sv, n_valid = downsampled
         frame = len(self.poses)
@@ -524,8 +557,8 @@ class OdometryPipeline:
                 pose = np.asarray(res.T, np.float32)
                 prev = self.poses[-1]
                 if not reacquire:
-                    self._velocity = (np.linalg.inv(prev) @ pose).astype(
-                        np.float32)
+                    self._velocity = _compose(
+                        np.linalg.inv(np.asarray(prev, np.float64)), pose)
                 # else: the previous (coasted) pose was wrong, so the pose
                 # delta is correction + motion entangled — the decayed
                 # coast velocity is the better motion estimate; keep it.
@@ -533,10 +566,8 @@ class OdometryPipeline:
                     if defer_fuse:
                         fuse_req = FuseRequest(src=src, sv=sv, pose=pose)
                     else:
-                        self.submap.insert(
-                            transform_points(jnp.asarray(pose, jnp.float32),
-                                             src),
-                            center=pose[:3, 3], valid=sv)
+                        self.submap.insert_scan(src, pose, sv,
+                                                lanes=self._lanes)
             else:
                 pose = np.asarray(T0, np.float32)
                 # decay the motion model: coasting frames must bleed speed

@@ -118,8 +118,8 @@ def solve_point_to_plane(src: jax.Array, dst: jax.Array,
     r = jnp.sum(n * (p - q), axis=-1)                       # (N,)
     a = jnp.concatenate([jnp.cross(p, n), n], axis=-1)      # (N, 6)
     aw = a * w[:, None]
-    A = aw.T @ a                                            # (6, 6) MXU
-    b = -(aw.T @ r)                                         # (6,)
+    A = jnp.matmul(aw.T, a, precision="highest")                           # (6, 6) MXU
+    b = -jnp.matmul(aw.T, r, precision="highest")                          # (6,)
     return solve_normal_equations(A, b, damping).astype(src.dtype)
 
 

@@ -60,9 +60,9 @@ def svd3x3(M: jax.Array, sweeps: int = 8):
         A, V = carry
         for (p, q) in _PIVOTS:
             col_p, col_q = A[:, p], A[:, q]
-            a_pp = col_p @ col_p
-            a_qq = col_q @ col_q
-            a_pq = col_p @ col_q
+            a_pp = jnp.dot(col_p, col_p, precision="highest")
+            a_qq = jnp.dot(col_q, col_q, precision="highest")
+            a_pq = jnp.dot(col_p, col_q, precision="highest")
             c, s = _jacobi_rotation(a_pp, a_qq, a_pq, eps)
             A, V = _apply_right_rotation(A, V, p, q, c, s)
         return (A, V), None
@@ -85,12 +85,12 @@ def svd3x3(M: jax.Array, sweeps: int = 8):
     U = work / safe[None, :]
     tol = 1e-12 * jnp.maximum(s[0], 1e-30)
     u0 = jnp.where(s[0] > tol, U[:, 0], jnp.array([1.0, 0.0, 0.0], jnp.float32))
-    u1_raw = U[:, 1] - (U[:, 1] @ u0) * u0
+    u1_raw = U[:, 1] - jnp.dot(U[:, 1], u0, precision="highest") * u0
     u1_norm = jnp.linalg.norm(u1_raw)
     u1 = jnp.where(jnp.logical_and(s[1] > tol, u1_norm > 1e-20),
                    u1_raw / jnp.maximum(u1_norm, 1e-30), _any_orthogonal(u0))
     u2_cross = jnp.cross(u0, u1)
-    sign = jnp.where(u2_cross @ U[:, 2] < 0.0, -1.0, 1.0)
+    sign = jnp.where(jnp.dot(u2_cross, U[:, 2], precision="highest") < 0.0, -1.0, 1.0)
     u2 = jnp.where(s[2] > tol, sign * u2_cross, u2_cross)
     U = jnp.stack([u0, u1, u2], axis=1)
     return U.astype(dtype), s.astype(dtype), V.T.astype(dtype)
@@ -100,7 +100,7 @@ def _any_orthogonal(u: jax.Array) -> jax.Array:
     """A unit vector orthogonal to u (u assumed unit, possibly axis-aligned)."""
     # Pick the axis least aligned with u, Gram-Schmidt it.
     axis = jnp.eye(3, dtype=u.dtype)[jnp.argmin(jnp.abs(u))]
-    v = axis - (axis @ u) * u
+    v = axis - jnp.dot(axis, u, precision="highest") * u
     return v / jnp.maximum(jnp.linalg.norm(v), 1e-30)
 
 

@@ -82,7 +82,8 @@ def accumulate_moments(rel: jax.Array, w: jax.Array):
     relf = rel.astype(jnp.float32)
     cnt = jnp.sum(wf, axis=-1)
     s = jnp.sum(relf * wf[..., None], axis=-2)
-    ss = jnp.einsum("...ci,...cj->...ij", relf * wf[..., None], relf)
+    ss = jnp.einsum("...ci,...cj->...ij", relf * wf[..., None], relf,
+                    precision="highest")
     return cnt, s, ss
 
 

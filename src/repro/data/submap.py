@@ -181,6 +181,78 @@ def _fuse_state_jit(state, new_pts, new_valid, center,
     return fuse_state(state, new_pts, new_valid, center, params)
 
 
+# Scan-level map stages, batched over lanes. The registration service runs
+# them over its fleet, and a standalone pipeline runs the same executables
+# with its frame at lane 0 of as many lanes (``lane0_batch``): a lane of a
+# batched program does not depend on the other lanes, so the two agree bit
+# for bit on any backend, where an eager or unbatched evaluation of the
+# same math need not round the same way. (``core.transform`` is imported
+# where used: ``repro.core`` imports this module.)
+
+def lane0_batch(x, fill, lanes: int) -> jax.Array:
+    """``x`` at lane 0 of a ``(lanes, ...)`` batch, ``fill`` (broadcast to
+    ``x``'s shape) in every other lane."""
+    x = jnp.asarray(x)
+    first = (jnp.arange(lanes) == 0).reshape((lanes,) + (1,) * x.ndim)
+    return jnp.where(first, x[None], jnp.asarray(fill, x.dtype))
+
+
+def fuse_scan(state: tuple, scan, valid, pose, params: SubmapParams):
+    """Fuse a sensor-frame ``scan`` taken at ``pose`` (4x4): the world-
+    frame scan through :func:`fuse_state`, re-anchored on the pose's
+    position. Returns ``(state', occupied, dropped)``."""
+    from repro.core.transform import transform_points
+    return fuse_state(state, transform_points(pose, scan), valid,
+                      pose[:3, 3], params)
+
+
+def outside_lattice_frac(pose, scan, valid, origin, params: SubmapParams):
+    """Fraction of the valid rows of a sensor-frame ``scan``, placed at
+    ``pose``, that fall outside the lattice anchored at ``origin`` — the
+    low-overlap/teleport signal. A bounds check; no grid build."""
+    from repro.core.transform import transform_points
+    c = jnp.floor((transform_points(pose, scan) - origin)
+                  / params.voxel_size)
+    inb = jnp.all((c >= 0) & (c < jnp.asarray(params.dims, jnp.float32)),
+                  axis=-1)
+    n_valid = jnp.maximum(jnp.sum(valid), 1)
+    return jnp.sum(jnp.logical_and(valid, ~inb)) / n_valid
+
+
+def fuse_lane(state: tuple, scan, valid, pose, accept,
+              params: SubmapParams):
+    """One lane's accept-gated :func:`fuse_scan`. A lane that does not
+    accept passes its state through bit-unchanged and drops no cells;
+    occupancy reports the kept state."""
+    fused, occ, dropped = fuse_scan(state, scan, valid, pose, params)
+    kept = jax.tree_util.tree_map(
+        lambda new, old: jnp.where(accept, new, old), fused, state)
+    occ_kept = jnp.where(accept, occ, jnp.sum(state_views(kept, params)[1]))
+    return kept, occ_kept, jnp.where(accept, dropped, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("params",),
+                   donate_argnums=(0,))
+def fuse_lanes(state_b: tuple, scan_b, valid_b, pose_b, accept_b,
+               params: SubmapParams):
+    """:func:`fuse_lane` over stacked ``(L, ...)`` state tuples. The
+    incoming state is donated: the largest arrays of a fleet reuse their
+    device allocation in place. Returns ``(state_b', occupied_b,
+    dropped_b)``."""
+    return jax.vmap(
+        lambda st, s, v, p, a: fuse_lane(st, s, v, p, a, params))(
+            state_b, scan_b, valid_b, pose_b, accept_b)
+
+
+@functools.partial(jax.jit, static_argnames=("params",))
+def outside_lattice_fracs(pose_b, scan_b, valid_b, origin_b,
+                          params: SubmapParams):
+    """:func:`outside_lattice_frac` over ``(L, ...)`` lanes."""
+    return jax.vmap(
+        lambda T, s, v, o: outside_lattice_frac(T, s, v, o, params))(
+            pose_b, scan_b, valid_b, origin_b)
+
+
 @functools.partial(jax.jit, static_argnames=("params",))
 def _fuse(map_pts, map_valid, new_pts, new_valid, center,
           params: SubmapParams):
@@ -235,6 +307,22 @@ class Submap:
             self.params)
         self.frames_inserted += 1
         self.dropped_cells += int(dropped)
+
+    def insert_scan(self, scan, pose, valid, lanes: int = 1) -> None:
+        """Fuse a (N, 3) sensor-frame scan taken at ``pose`` (4x4, map
+        frame) through :func:`fuse_lanes` at lane 0 of ``lanes``; evict +
+        re-anchor around the pose's position."""
+        idle = empty_state(self.params)
+        state_b, _, dropped = fuse_lanes(
+            tuple(lane0_batch(leaf, fill, lanes)
+                  for leaf, fill in zip(self.state, idle)),
+            lane0_batch(jnp.asarray(scan, jnp.float32), PAD_SENTINEL, lanes),
+            lane0_batch(jnp.asarray(valid, bool), False, lanes),
+            lane0_batch(jnp.asarray(pose, jnp.float32), jnp.eye(4), lanes),
+            lane0_batch(True, False, lanes), self.params)
+        self.state = tuple(leaf[0] for leaf in state_b)
+        self.frames_inserted += 1
+        self.dropped_cells += int(dropped[0])
 
     # -- decoded views -----------------------------------------------------
     @property

@@ -9,9 +9,10 @@ accumulation; this kernel is that pipeline on the TPU:
 
   * grid = (N/bn, CK/bc): query blocks "parallel", the candidate axis
     innermost/"arbitrary". Per candidate tile the kernel computes the
-    (bn, bc) distance plane in VMEM, reduces it to a per-query running
+    (bc, bn) distance plane in VMEM (queries along lanes, candidates along
+    sublanes — ``kernels.common``), reduces it to a per-query running
     (min, winner-coordinates[, winner-normal]) carry — the winner's
-    *values* are selected in-register via a one-hot lane reduction, so no
+    *values* are selected in-register via a one-hot reduction, so no
     index gather ever revisits earlier tiles.
   * on the **last** candidate tile the carried winner is gated
     (``d² ≤ gate²``, recomputed exact in fp32 from the carried
@@ -44,7 +45,7 @@ wins. The prune pays half-width math up front to skip full-width math on
 cold tiles; whether that nets out is hardware-dependent, which is
 exactly the knob the autotune sweep (``tools/autotune_fused.py``) flips.
 
-Point-to-plane normals ride as candidate payload: three extra (bn, bc)
+Point-to-plane normals ride as candidate payload: three extra (bc, bn)
 planes gathered through the same slot tables, selected by the same
 one-hot carry — matching Sugiura & Matsutani's feature-payload streaming
 (arXiv:2203.05763).
@@ -58,17 +59,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.nn_search_grid import _MASK_COORD, gather_candidates
+from repro.core.nn_search_grid import _MASK_COORD, gather_candidate_planes
 from repro.data.voxelize import VoxelGrid, build_voxel_grid
-from repro.kernels.common import pallas_call_kwargs, round_up
+from repro.kernels.common import (argmin_rows, pallas_call_kwargs,
+                                  query_rows, round_up)
 
-# Default fused-kernel configuration. bn/bc/prune are the autotune axes;
-# the committed choice (see BENCH_fused_autotune.json, re-run via
-# ``python tools/autotune_fused.py``) is baked in here so library users
-# get the tuned config with no file I/O.
+# Default fused-kernel configuration. bn/bc/prune are the autotune axes
+# (``python tools/autotune_fused.py``). The committed choice comes from an
+# interpret-mode sweep on a CPU (BENCH_fused_autotune.json); it has not
+# been tuned on a chip.
 class FusedConfig(NamedTuple):
-    bn: int = 512            # query tile (rows per grid step)
-    bc: int = 256            # candidate plane width (lanes per grid step)
+    bn: int = 512            # query tile (lanes per grid step)
+    bc: int = 256            # candidate tile (sublanes per grid step)
     prune: bool = False      # bf16 coarse-distance screen (see module doc)
     prune_margin: float = 1.1  # gate widening for the bf16 screen
 
@@ -132,29 +134,29 @@ def _fused_kernel(*refs, bc: int, nc: int, gate2: float, prune_gate2: float,
         # to the unpruned pass (screened rows are provably out-of-gate and
         # would carry weight 0 regardless of which of them wins).
         bf = jnp.bfloat16
-        dxb = cx.astype(bf) - qx.astype(bf)[:, None]
-        dyb = cy.astype(bf) - qy.astype(bf)[:, None]
-        dzb = cz.astype(bf) - qz.astype(bf)[:, None]
+        dxb = cx.astype(bf) - qx.astype(bf)
+        dyb = cy.astype(bf) - qy.astype(bf)
+        dzb = cz.astype(bf) - qz.astype(bf)
         d2b = (dxb * dxb + dyb * dyb + dzb * dzb).astype(jnp.float32)
         keep = d2b <= prune_gate2
 
     def _update():
-        dx = cx - qx[:, None]
-        dy = cy - qy[:, None]
-        dz = cz - qz[:, None]
+        dx = cx - qx
+        dy = cy - qy
+        dz = cz - qz
         d2 = dx * dx + dy * dy + dz * dz
         if prune:
             d2 = jnp.where(keep, d2, jnp.inf)
-        local_arg = jnp.argmin(d2, axis=1).astype(jnp.int32)
-        local_min = jnp.min(d2, axis=1)
+        local_min, local_arg = argmin_rows(d2)
         # In-register winner-value selection: one-hot of the tile argmin,
         # so the carry holds coordinates/normals, never indices to
         # re-gather.
         onehot = jax.lax.broadcasted_iota(
-            jnp.int32, d2.shape, 1) == local_arg[:, None]
+            jnp.int32, d2.shape, 0) == local_arg
 
         def _sel(vals):
-            return jnp.sum(jnp.where(onehot, vals, 0.0), axis=1)
+            return jnp.sum(jnp.where(onehot, vals, 0.0), axis=0,
+                           keepdims=True)
 
         # Strict < keeps the earliest tile/slot on ties (first-match
         # semantics, same as the NN kernels).
@@ -172,7 +174,7 @@ def _fused_kernel(*refs, bc: int, nc: int, gate2: float, prune_gate2: float,
 
     if prune:
         # Whole-tile skip: when the bf16 screen rejects every candidate in
-        # the (bn, bc) tile, the fp32 pass is provably a no-op (all-inf
+        # the (bc, bn) tile, the fp32 pass is provably a no-op (all-inf
         # local_min never improves the carry) — don't execute it.
         pl.when(jnp.any(keep))(_update)
     else:
@@ -229,10 +231,12 @@ def fused_moment_sweep(q: jax.Array, cand: jax.Array,
 
     Args:
       q: (N, 3) transformed source points (the iteration's queries).
-      cand: (N, CK, 3) candidate coordinates (masked slots = sentinel).
+      cand: (N, CK, 3) candidate coordinates (masked slots = sentinel), or
+        the same as three (N, CK) planes (``gather_candidate_planes``).
       src_valid: optional (N,) bool/float mask; invalid rows get weight 0.
-      cand_normals: (N, CK, 3) candidate normals — required for (and
-        selects) the point-to-plane moment set; invalid slots must be 0.
+      cand_normals: candidate normals, shaped like ``cand`` — required for
+        (and selects) the point-to-plane moment set; invalid slots must
+        be 0.
       gate / robust_kernel / robust_scale: the ``ICPParams`` weighting
         (static — they specialise the kernel).
       bn / bc / prune / prune_margin: kernel config (see ``FusedConfig``).
@@ -242,27 +246,27 @@ def fused_moment_sweep(q: jax.Array, cand: jax.Array,
       queries (padded rows contribute zero by construction).
     """
     plane = cand_normals is not None
-    n, ck = cand.shape[0], cand.shape[1]
+    n = q.shape[0]
+    ck = (cand[0] if isinstance(cand, tuple) else cand).shape[1]
     n_pad, ck_pad = round_up(n, bn), round_up(ck, bc)
-    qf = q.astype(jnp.float32)
+
+    def kernel_planes(x, fill):
+        # -> three padded (CK, N) f32 planes, candidates on sublanes
+        planes = x if isinstance(x, tuple) else tuple(
+            x[..., a] for a in range(3))
+        return [jnp.pad(p.astype(jnp.float32),
+                        ((0, n_pad - n), (0, ck_pad - ck)),
+                        constant_values=fill).T for p in planes]
+
+    qf = jnp.pad(q.astype(jnp.float32), ((0, n_pad - n), (0, 0)))
     sv = (jnp.ones((n,), jnp.float32) if src_valid is None
           else src_valid.astype(jnp.float32))
-    candf = cand.astype(jnp.float32)
-    if n_pad > n or ck_pad > ck:
-        candf = jnp.pad(candf, ((0, n_pad - n), (0, ck_pad - ck), (0, 0)),
-                        constant_values=_MASK_COORD)
-        qf = jnp.pad(qf, ((0, n_pad - n), (0, 0)))
-        sv = jnp.pad(sv, (0, n_pad - n))
-        if plane:
-            cand_normals = jnp.pad(
-                cand_normals.astype(jnp.float32),
-                ((0, n_pad - n), (0, ck_pad - ck), (0, 0)))
+    sv = jnp.pad(sv, (0, n_pad - n))
     grid = (n_pad // bn, ck_pad // bc)
-    qx, qy, qz = (qf[:, a] for a in range(3))
-    inputs = [qx, qy, qz, sv] + [candf[:, :, a] for a in range(3)]
+    inputs = [*query_rows(qf), sv[None, :],
+              *kernel_planes(cand, _MASK_COORD)]
     if plane:
-        inputs += [cand_normals[:, :, a].astype(jnp.float32)
-                   for a in range(3)]
+        inputs += kernel_planes(cand_normals, 0.0)
     names = moment_names(plane)
     n_out = _carry_count(plane) + len(names)
     kernel = functools.partial(
@@ -270,15 +274,15 @@ def fused_moment_sweep(q: jax.Array, cand: jax.Array,
         prune_gate2=(float(gate) * float(prune_margin)) ** 2,
         robust=robust_kernel, scale=float(robust_scale),
         plane=plane, prune=prune)
-    vspec = pl.BlockSpec((bn,), lambda i, j: (i,))
-    cspec = pl.BlockSpec((bn, bc), lambda i, j: (i, j))
+    vspec = pl.BlockSpec((1, bn), lambda i, j: (0, i))
+    cspec = pl.BlockSpec((bc, bn), lambda i, j: (j, i))
     in_specs = [vspec] * 4 + [cspec] * (6 if plane else 3)
     call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=tuple(vspec for _ in range(n_out)),
-        out_shape=tuple(jax.ShapeDtypeStruct((n_pad,), jnp.float32)
+        out_shape=tuple(jax.ShapeDtypeStruct((1, n_pad), jnp.float32)
                         for _ in range(n_out)),
         **pallas_call_kwargs(interpret, ("parallel", "arbitrary")),
     )
@@ -361,13 +365,13 @@ def make_fused_fn(grid: VoxelGrid, params, target_normals=None, *,
                          "as candidate payload)")
 
     def fused_fn(src_t: jax.Array, src_valid: jax.Array | None = None):
-        cand_pts, cand_idx, cand_valid = gather_candidates(
+        cand_pts, cand_idx, cand_valid = gather_candidate_planes(
             src_t, grid, max_per_cell, rings)
         cand_n = None
         if plane:
-            cand_n = jnp.where(cand_valid[..., None],
-                               jnp.take(target_normals, cand_idx, axis=0),
-                               0.0)
+            cand_n = tuple(
+                jnp.where(cand_valid, target_normals[:, a][cand_idx], 0.0)
+                for a in range(3))
         sums = fused_moment_sweep(
             src_t, cand_pts, src_valid, cand_n,
             gate=params.max_correspondence_distance,

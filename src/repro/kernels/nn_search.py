@@ -27,8 +27,11 @@ FPGA -> TPU mapping (DESIGN.md §2):
     Pallas's grid pipeline provides the FIFO-style overlap of the paper's
     4-stage streaming design (load of block j+1 overlaps compute of j).
 
-  * The paper's comparison tree (CMP TR) is the in-tile `min`/`argmin` lane
-    reduction on the VPU.
+  * The paper's comparison tree (CMP TR) is the in-tile `min`/`argmin`
+    reduction on the VPU. The score tile is laid out (bm, bn) — targets on
+    sublanes, queries on lanes — so the reduction runs over sublanes and
+    the per-query (best_d2, best_idx) carry is a lane-dense (1, bn) row
+    (``kernels.common``).
 
 Grid: (N/bn, M/bm), target axis innermost ("arbitrary" semantics — it
 carries the running min; the source axis is "parallel").
@@ -41,32 +44,36 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import pallas_call_kwargs
+from repro.core.nn_search import merge_two_best
+from repro.kernels.common import argmin_rows, pallas_call_kwargs
 
 AUG_ROWS = 8
 
 
-def _nn_kernel(src_ref, dst_ref, best_d2_ref, best_idx_ref, *, bm: int):
+def _nn_kernel(src_ref, dst_ref, *out_refs, bm: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        best_d2_ref[...] = jnp.full_like(best_d2_ref, jnp.inf)
-        best_idx_ref[...] = jnp.zeros_like(best_idx_ref)
+        for ref in out_refs:
+            ref[...] = (jnp.full_like(ref, jnp.inf)
+                        if ref.dtype == jnp.float32 else jnp.zeros_like(ref))
 
-    # (8, bn) x (8, bm) -> (bn, bm): one MXU tile, fp32 accumulation.
+    # (8, bm) x (8, bn) -> (bm, bn): one MXU tile, fp32 accumulation.
     scores = jax.lax.dot_general(
-        src_ref[...], dst_ref[...], (((0,), (0,)), ((), ())),
+        dst_ref[...], src_ref[...], (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
-    # CMP-tree stage: per-row reduction over the bm candidates.
-    local_arg = jnp.argmin(scores, axis=1).astype(jnp.int32)
-    local_min = jnp.min(scores, axis=1)
-    # MIN-block stage: strict < keeps the earliest index on ties, matching
-    # the oracle's first-match semantics.
-    cand_idx = j * bm + local_arg
-    improved = local_min < best_d2_ref[...]
-    best_d2_ref[...] = jnp.where(improved, local_min, best_d2_ref[...])
-    best_idx_ref[...] = jnp.where(improved, cand_idx, best_idx_ref[...])
+    # CMP-tree stage: per-query best and runner-up over the bm candidates.
+    v1, a1 = argmin_rows(scores)
+    rows = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+    v2, a2 = argmin_rows(jnp.where(rows == a1, jnp.inf, scores))
+    # MIN-block stage: the running two best; the earliest index wins ties,
+    # matching the oracle's first-match semantics.
+    merged = merge_two_best(tuple(ref[...] for ref in out_refs),
+                            (v1, j * bm + a1, v2, j * bm + a2))
+    for ref, val in zip(out_refs, merged):
+        ref[...] = val
 
 
 def nn_search_kernel(src_aug: jax.Array, dst_aug: jax.Array,
@@ -77,14 +84,15 @@ def nn_search_kernel(src_aug: jax.Array, dst_aug: jax.Array,
     Args:
       src_aug: (8, N) from ``ref.augment_source`` — N must be a multiple of bn.
       dst_aug: (8, M) from ``ref.augment_target`` — M must be a multiple of bm.
-      bn, bm: VMEM tile sizes. Defaults give tiles of
-        src 8*512*4 = 16 KiB, dst 8*1024*4 = 32 KiB, scores 512*1024*4 = 2 MiB
-        — comfortably double-bufferable in ~128 MiB v5e VMEM while keeping
-        the MXU dims (bn, bm) at 128-multiples.
+      bn, bm: VMEM tile sizes, multiples of 128. Defaults give tiles of
+        src 8*512*4 = 16 KiB, dst 8*1024*4 = 32 KiB, scores 1024*512*4 =
+        2 MiB.
       interpret: tri-state (``kernels.common``): None = auto (compiled on
         TPU, interpreter elsewhere).
     Returns:
-      (best_d2, best_idx): (N,) fp32 (unclamped) and (N,) int32.
+      (best_d2, best_idx, next_d2, next_idx): the best and runner-up
+      expansion scores, (N,) fp32 (unclamped), and their (N,) int32
+      target indices.
     """
     n = src_aug.shape[1]
     m = dst_aug.shape[1]
@@ -94,16 +102,13 @@ def nn_search_kernel(src_aug: jax.Array, dst_aug: jax.Array,
     grid = (n // bn, m // bm)
 
     kernel = functools.partial(_nn_kernel, bm=bm)
-    out_shape = (jax.ShapeDtypeStruct((n,), jnp.float32),
-                 jax.ShapeDtypeStruct((n,), jnp.int32))
+    out_shape = (jax.ShapeDtypeStruct((1, n), jnp.float32),
+                 jax.ShapeDtypeStruct((1, n), jnp.int32)) * 2
     in_specs = [
         pl.BlockSpec((AUG_ROWS, bn), lambda i, j: (0, i)),
         pl.BlockSpec((AUG_ROWS, bm), lambda i, j: (0, j)),
     ]
-    out_specs = (
-        pl.BlockSpec((bn,), lambda i, j: (i,)),
-        pl.BlockSpec((bn,), lambda i, j: (i,)),
-    )
+    out_specs = (pl.BlockSpec((1, bn), lambda i, j: (0, i)),) * 4
     call = pl.pallas_call(
         kernel,
         grid=grid,
@@ -112,7 +117,7 @@ def nn_search_kernel(src_aug: jax.Array, dst_aug: jax.Array,
         out_shape=out_shape,
         **pallas_call_kwargs(interpret, ("parallel", "arbitrary")),
     )
-    return call(src_aug, dst_aug)
+    return tuple(row[0] for row in call(src_aug, dst_aug))
 
 
 def vmem_bytes(bn: int, bm: int) -> dict:
@@ -120,7 +125,7 @@ def vmem_bytes(bn: int, bm: int) -> dict:
     src = AUG_ROWS * bn * 4
     dst = AUG_ROWS * bm * 4
     scores = bn * bm * 4
-    outs = bn * (4 + 4)
+    outs = 2 * bn * (4 + 4)
     return {
         "src_tile": src, "dst_tile": dst, "scores": scores, "outputs": outs,
         "total_single": src + dst + scores + outs,

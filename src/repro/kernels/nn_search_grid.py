@@ -10,9 +10,10 @@ running (min, argmin) carry — in VMEM:
 
   * grid = (N/bn, CK/bc): query blocks are "parallel", the candidate axis
     is innermost/"arbitrary" carrying the running min, exactly like the
-    brute kernel's target axis.
+    brute kernel's target axis. Candidates run along sublanes and queries
+    along lanes (``kernels.common``).
   * the candidate set is per-query, so the distance tile is an *elementwise*
-    (bn, bc) op on coordinate planes (VPU work) rather than a matmul — with
+    (bc, bn) op on coordinate planes (VPU work) rather than a matmul — with
     CK = 27*max_per_cell ≈ a few hundred, there is no shared-operand
     structure for the MXU to exploit, and the whole sweep is tiny compared
     to the O(M) brute tile stream it replaces.
@@ -34,7 +35,9 @@ from jax.experimental import pallas as pl
 
 from repro.core.nn_search_grid import _MASK_COORD, gather_candidates
 from repro.data.voxelize import VoxelGrid
-from repro.kernels.common import pallas_call_kwargs, round_up as _round_up
+from repro.kernels.common import (argmin_rows, candidate_planes,
+                                  pallas_call_kwargs, query_rows,
+                                  round_up as _round_up)
 
 
 def _grid_nn_kernel(qx_ref, qy_ref, qz_ref, cx_ref, cy_ref, cz_ref,
@@ -46,13 +49,12 @@ def _grid_nn_kernel(qx_ref, qy_ref, qz_ref, cx_ref, cy_ref, cz_ref,
         best_d2_ref[...] = jnp.full_like(best_d2_ref, jnp.inf)
         best_slot_ref[...] = jnp.zeros_like(best_slot_ref)
 
-    # (bn, bc) distance tile from coordinate planes: pure VPU.
-    dx = qx_ref[...][:, None] - cx_ref[...]
-    dy = qy_ref[...][:, None] - cy_ref[...]
-    dz = qz_ref[...][:, None] - cz_ref[...]
+    # (bc, bn) distance tile from coordinate planes: pure VPU.
+    dx = qx_ref[...] - cx_ref[...]
+    dy = qy_ref[...] - cy_ref[...]
+    dz = qz_ref[...] - cz_ref[...]
     d2 = dx * dx + dy * dy + dz * dz
-    local_arg = jnp.argmin(d2, axis=1).astype(jnp.int32)
-    local_min = jnp.min(d2, axis=1)
+    local_min, local_arg = argmin_rows(d2)
     # Strict < keeps the earliest slot on ties (first-match semantics).
     improved = local_min < best_d2_ref[...]
     best_d2_ref[...] = jnp.where(improved, local_min, best_d2_ref[...])
@@ -77,24 +79,21 @@ def candidate_sweep_kernel(q: jax.Array, cand: jax.Array, *,
     assert n % bn == 0, (n, bn)
     assert ck % bc == 0, (ck, bc)
     grid = (n // bn, ck // bc)
-    qx, qy, qz = (q[:, a].astype(jnp.float32) for a in range(3))
-    cx, cy, cz = (cand[:, :, a].astype(jnp.float32) for a in range(3))
     kernel = functools.partial(_grid_nn_kernel, bc=bc)
-    out_shape = (jax.ShapeDtypeStruct((n,), jnp.float32),
-                 jax.ShapeDtypeStruct((n,), jnp.int32))
-    qspec = pl.BlockSpec((bn,), lambda i, j: (i,))
-    cspec = pl.BlockSpec((bn, bc), lambda i, j: (i, j))
-    out_specs = (pl.BlockSpec((bn,), lambda i, j: (i,)),
-                 pl.BlockSpec((bn,), lambda i, j: (i,)))
+    out_shape = (jax.ShapeDtypeStruct((1, n), jnp.float32),
+                 jax.ShapeDtypeStruct((1, n), jnp.int32))
+    qspec = pl.BlockSpec((1, bn), lambda i, j: (0, i))
+    cspec = pl.BlockSpec((bc, bn), lambda i, j: (j, i))
     call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[qspec, qspec, qspec, cspec, cspec, cspec],
-        out_specs=out_specs,
+        out_specs=(qspec, qspec),
         out_shape=out_shape,
         **pallas_call_kwargs(interpret, ("parallel", "arbitrary")),
     )
-    return call(qx, qy, qz, cx, cy, cz)
+    best_d2, best_slot = call(*query_rows(q), *candidate_planes(cand))
+    return best_d2[0], best_slot[0]
 
 
 def nn_search_grid_pallas(src: jax.Array, grid: VoxelGrid, *,

@@ -17,9 +17,10 @@ moment accumulation** instead of a running min:
   * masked candidate slots arrive pre-filled with far-sentinel coordinates
     (``core.nn_search_grid``), so the radius gate ``d² ≤ r²`` rejects them
     with no separate mask input — the finite-sentinel trick again.
-  * per (bn, bc) tile the work is elementwise multiplies + a lane
-    reduction (VPU); there is no shared operand for the MXU, exactly like
-    the NN candidate sweep it mirrors.
+  * per (bc, bn) tile the work is elementwise multiplies + a sublane
+    reduction (VPU; queries run along lanes, ``kernels.common``); there is
+    no shared operand for the MXU, exactly like the NN candidate sweep it
+    mirrors.
 
 The eigen-decomposition epilogue is shared with the XLA path
 (:func:`repro.data.normals.moments_to_normals`), so the kernel's contract
@@ -37,7 +38,8 @@ from repro.core.nn_search_grid import _MASK_COORD, gather_candidates
 from repro.data.normals import (NormalParams, moments_to_normals,
                                 orient_normals)
 from repro.data.voxelize import VoxelGrid, build_voxel_grid
-from repro.kernels.common import pallas_call_kwargs, round_up as _round_up
+from repro.kernels.common import (candidate_planes, pallas_call_kwargs,
+                                  query_rows, round_up as _round_up)
 
 # Output order of the moment planes: count, Σdx, Σdy, Σdz, then the six
 # unique entries of the symmetric second-moment matrix.
@@ -53,16 +55,16 @@ def _moment_sweep_kernel(qx_ref, qy_ref, qz_ref, cx_ref, cy_ref, cz_ref,
         for ref in out_refs:
             ref[...] = jnp.zeros_like(ref)
 
-    dx = cx_ref[...] - qx_ref[...][:, None]
-    dy = cy_ref[...] - qy_ref[...][:, None]
-    dz = cz_ref[...] - qz_ref[...][:, None]
+    dx = cx_ref[...] - qx_ref[...]
+    dy = cy_ref[...] - qy_ref[...]
+    dz = cz_ref[...] - qz_ref[...]
     d2 = dx * dx + dy * dy + dz * dz
     w = (d2 <= r2).astype(jnp.float32)
     planes = (w, w * dx, w * dy, w * dz,
               w * dx * dx, w * dy * dy, w * dz * dz,
               w * dx * dy, w * dx * dz, w * dy * dz)
     for ref, plane in zip(out_refs, planes):
-        ref[...] += jnp.sum(plane, axis=1)
+        ref[...] += jnp.sum(plane, axis=0, keepdims=True)
 
 
 def moment_sweep_kernel(q: jax.Array, cand: jax.Array, radius: float, *,
@@ -84,26 +86,22 @@ def moment_sweep_kernel(q: jax.Array, cand: jax.Array, radius: float, *,
     assert n % bn == 0, (n, bn)
     assert ck % bc == 0, (ck, bc)
     grid = (n // bn, ck // bc)
-    qx, qy, qz = (q[:, a].astype(jnp.float32) for a in range(3))
-    cx, cy, cz = (cand[:, :, a].astype(jnp.float32) for a in range(3))
     kernel = functools.partial(_moment_sweep_kernel,
                                r2=float(radius) ** 2)
-    out_shape = tuple(jax.ShapeDtypeStruct((n,), jnp.float32)
+    out_shape = tuple(jax.ShapeDtypeStruct((1, n), jnp.float32)
                       for _ in _MOMENTS)
-    qspec = pl.BlockSpec((bn,), lambda i, j: (i,))
-    cspec = pl.BlockSpec((bn, bc), lambda i, j: (i, j))
-    out_specs = tuple(pl.BlockSpec((bn,), lambda i, j: (i,))
-                      for _ in _MOMENTS)
+    qspec = pl.BlockSpec((1, bn), lambda i, j: (0, i))
+    cspec = pl.BlockSpec((bc, bn), lambda i, j: (j, i))
     call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[qspec, qspec, qspec, cspec, cspec, cspec],
-        out_specs=out_specs,
+        out_specs=tuple(qspec for _ in _MOMENTS),
         out_shape=out_shape,
         **pallas_call_kwargs(interpret, ("parallel", "arbitrary")),
     )
-    cnt, sx, sy, sz, sxx, syy, szz, sxy, sxz, syz = call(qx, qy, qz,
-                                                         cx, cy, cz)
+    cnt, sx, sy, sz, sxx, syy, szz, sxy, sxz, syz = (
+        row[0] for row in call(*query_rows(q), *candidate_planes(cand)))
     s = jnp.stack([sx, sy, sz], axis=-1)
     ss = jnp.stack([
         jnp.stack([sxx, sxy, sxz], axis=-1),

@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.nn_search import closer_of_two
 from repro.kernels import ref
 from repro.kernels.common import round_up as _round_up
 from repro.kernels.nn_search import nn_search_kernel
@@ -33,7 +34,7 @@ def nn_search_pallas(src: jax.Array, dst: jax.Array,
     n_pad, m_pad = _round_up(n, bn), _round_up(m, bm)
     src_aug = ref.augment_source(src, T, pad_to=n_pad)
     dst_aug = ref.augment_target(dst, pad_to=m_pad)
-    d2, idx = nn_search_kernel(src_aug, dst_aug, bn=bn, bm=bm,
+    d2, idx, _, _ = nn_search_kernel(src_aug, dst_aug, bn=bn, bm=bm,
                                interpret=interpret)
     return jnp.maximum(d2[:n], 0.0), idx[:n]
 
@@ -48,7 +49,11 @@ def resident_nn_fn(dst: jax.Array, *, bn: int = 512, bm: int = 1024,
     small source cloud per iteration (the BRAM-resident analogue,
     DESIGN.md §2). The closure matches the ``core.icp`` ``nn_fn(src, dst)``
     contract but ignores its second argument in favour of the resident
-    augmentation.
+    augmentation. Like ``core.nn_search`` it returns ``(d2, idx, points)``
+    and picks between the two best candidates by the directly computed
+    distance, which it also returns: the expansion's cancellation at scene
+    scale would otherwise swap near-tied neighbours and move points across
+    the correspondence gate.
 
     Padded/invalid target rows must already carry far-sentinel coordinates
     (as ``repro.data.collate`` produces) so they cannot win the argmin.
@@ -59,9 +64,10 @@ def resident_nn_fn(dst: jax.Array, *, bn: int = 512, bm: int = 1024,
     def nn_fn(src: jax.Array, _target=None):
         n = src.shape[0]
         src_aug = ref.augment_source(src, pad_to=_round_up(n, bn))
-        d2, idx = nn_search_kernel(src_aug, dst_aug, bn=bn, bm=bm,
-                                   interpret=interpret)
-        return jnp.maximum(d2[:n], 0.0), idx[:n]
+        _, i1, s2, i2 = nn_search_kernel(src_aug, dst_aug, bn=bn, bm=bm,
+                                         interpret=interpret)
+        # A runner-up score of 1e30 or more is target padding.
+        return closer_of_two(src, dst, i1[:n], i2[:n], s2[:n] < 1e29)
 
     return nn_fn
 
@@ -83,7 +89,7 @@ def make_frame_engine(dst: jax.Array, *, bn: int = 512, bm: int = 1024,
         n = src.shape[0]
         n_pad = _round_up(n, bn)
         src_aug = ref.augment_source(src, T, pad_to=n_pad)
-        d2, idx = nn_search_kernel(src_aug, dst_aug, bn=bn, bm=bm,
+        d2, idx, _, _ = nn_search_kernel(src_aug, dst_aug, bn=bn, bm=bm,
                                    interpret=interpret)
         return jnp.maximum(d2[:n], 0.0), idx[:n]
 

@@ -48,7 +48,8 @@ def augment_source(src: jax.Array, T: jax.Array | None = None,
     assert np_ >= n
     p = src.astype(jnp.float32)
     if T is not None:
-        p = p @ T[:3, :3].T.astype(jnp.float32) + T[:3, 3].astype(jnp.float32)
+        p = (jnp.matmul(p, T[:3, :3].T.astype(jnp.float32), precision="highest")
+             + T[:3, 3].astype(jnp.float32))
     out = jnp.zeros((AUG_ROWS, np_), dtype=jnp.float32)
     out = out.at[0:3, :n].set(p.T)
     out = out.at[3, :n].set(1.0)
@@ -68,6 +69,7 @@ def nn_search_ref(src: jax.Array, dst: jax.Array,
     dst_aug = augment_target(dst)
     scores = jax.lax.dot_general(
         src_aug, dst_aug, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)            # (N, M)
     idx = jnp.argmin(scores, axis=1).astype(jnp.int32)
     d2 = jnp.take_along_axis(scores, idx[:, None], axis=1)[:, 0]
@@ -87,6 +89,7 @@ def nn_search_ref_blocked(src, dst, T, bn: int, bm: int):
     for j in range((m + m_pad) // bm):
         dblk = dst_aug[:, j * bm:(j + 1) * bm]
         scores = jax.lax.dot_general(src_aug, dblk, (((0,), (0,)), ((), ())),
+                                     precision=jax.lax.Precision.HIGHEST,
                                      preferred_element_type=jnp.float32)
         larg = jnp.argmin(scores, axis=1).astype(jnp.int32)
         lmin = jnp.take_along_axis(scores, larg[:, None], 1)[:, 0]

@@ -77,13 +77,10 @@ def _rules_for(mesh, global_batch: int, overrides: dict | None = None,
     return rules
 
 
-from repro.compat import cost_analysis as _cost_analysis  # noqa: E402
-
-
 def _collect(compiled, label: str, n_devices: int, cfg=None, shape=None,
              model_flops_override=None) -> dict:
     mem = compiled.memory_analysis()
-    naive = _cost_analysis(compiled)
+    naive = compiled.cost_analysis()
     hlo = compiled.as_text()
     cost = analyze_hlo(hlo)
     terms = roofline_terms(cost, cfg, shape, n_devices,
@@ -203,7 +200,7 @@ def _lower_lm_cell(arch: str, shape_name: str, mesh_name: str,
     out["rules"] = {k: list(v) if isinstance(v, tuple) else v
                     for k, v in rules.items()}
     print(compiled.memory_analysis())
-    ca = _cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     print({k: ca[k] for k in ("flops", "bytes accessed") if k in ca})
     return out
 
@@ -316,8 +313,10 @@ def run_all(out_dir: pathlib.Path, meshes=("single", "multi"),
                    "--out-dir", str(out_dir)]
             print("==>", " ".join(cmd), flush=True)
             t0 = time.time()
+            # The cell models forced host devices: keep it off the chip.
             proc = subprocess.run(cmd, timeout=timeout_s,
-                                  capture_output=True, text=True)
+                                  capture_output=True, text=True,
+                                  env={**os.environ, "JAX_PLATFORMS": "cpu"})
             dt = time.time() - t0
             if proc.returncode != 0:
                 err = {"label": f"{arch}/{shape}/{mesh_name}",

@@ -38,6 +38,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import FppsICP, ICPParams, get_engine
 from repro.core.baseline import kdtree_icp
 from repro.data.pointcloud import (SceneConfig, frame_pair_from_world,
@@ -101,14 +102,21 @@ def run_serve(args, cfg, params):
                                                   ServiceConfig)
 
     faults = parse_fault_spec(args.faults) if args.faults else None
-    # Fleet-sized scene regardless of --reduced: the round multiplies
-    # every shape by ``--streams``. Vehicles scan distinct worlds
-    # (``--seq + s``) at each sequence's own ground-truth speed, so the
-    # fleet mixes easy urban streams with the 2.5 m/frame highway
-    # outlier (seq 1) whose cold start outruns the 1 m gate — the demo's
-    # point is that its SUSPECT verdicts stay confined to that stream.
-    cfg = SceneConfig(n_ground=2500, n_walls=1800, n_poles=450,
-                      n_clutter=450, extent=25.0, sensor_range=30.0)
+    # Vehicles scan distinct worlds (``--seq + s``) at each sequence's own
+    # ground-truth speed, so the fleet mixes easy urban streams with the
+    # 2.5 m/frame highway outlier (seq 1) whose cold start outruns the 1 m
+    # gate — the demo's point is that its SUSPECT verdicts stay confined
+    # to that stream. Full size is the paper's scene with the
+    # ``OdometryConfig`` submap defaults; ``--reduced`` shrinks both, as
+    # the round multiplies every shape by ``--streams``.
+    odo = OdometryConfig(params=params._replace(max_iterations=30))
+    if args.reduced:
+        cfg = SceneConfig(n_ground=2500, n_walls=1800, n_poles=450,
+                          n_clutter=450, extent=25.0, sensor_range=30.0)
+        odo = odo._replace(
+            submap=SubmapParams(voxel_size=0.75, capacity=8192,
+                                dims=(96, 96, 24), evict_radius=25.0),
+            scan_budget=4096)
     fleet = {}
     for s in range(args.streams):
         scans = sequence_scans(args.seq + s, args.frames + 1, cfg)
@@ -123,11 +131,6 @@ def run_serve(args, cfg, params):
                 frames.append((scan, None))
         fleet[f"veh{s}"] = frames
 
-    odo = OdometryConfig(
-        params=params._replace(max_iterations=30),
-        submap=SubmapParams(voxel_size=0.75, capacity=8192,
-                            dims=(96, 96, 24), evict_radius=25.0),
-        scan_budget=4096)
     cap = max(sc.shape[0] for frames in fleet.values() for sc, _ in frames)
     svc = RegistrationService(ServiceConfig(
         slots=args.streams, scan_capacity=cap, odometry=odo))
@@ -212,6 +215,7 @@ def main(argv=None):
     ap.add_argument("--reduced", action="store_true",
                     help="smaller synthetic scenes (fast CI)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (SceneConfig(n_ground=9000, n_walls=6000, n_poles=1800,
                        n_clutter=1700, extent=40.0, sensor_range=45.0)

@@ -26,7 +26,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.models import layers as L
 from repro.models.moe import MoEConfig, capacity, route
 
@@ -124,8 +123,8 @@ def moe_forward_ep(p, x: jax.Array, cfg: MoEConfig, mesh, rules):
     metrics_spec = {k: P() for k in
                     ("load_balance_loss", "router_z_loss")}
     out_specs = (P(tok if tok else None, ax, None), metrics_spec)
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                       check_vma=False)
     out, metrics = fn(x, p["router"]["kernel"], p["wi"], p["wg"], p["wo"])
     metrics["dropped_frac"] = jnp.zeros((), jnp.float32)  # tracked locally
     metrics["moe_aux_total"] = (cfg.aux_loss_coef * metrics["load_balance_loss"]

@@ -42,8 +42,7 @@ def compressed_psum_mean(g: jax.Array, axis: str, ef: jax.Array):
     g: fp32 array (any shape; padded internally to n_dev chunks);
     ef: error-feedback residual, same shape. Returns (g_mean, new_ef).
     Must run inside shard_map with ``axis`` manual."""
-    from repro.compat import axis_size
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     shape = g.shape
     orig = 1
     for d in shape:

@@ -13,7 +13,9 @@ from __future__ import annotations
 
 import os
 
-if "--no-devices" not in os.sys.argv:  # parity with dryrun: 512 host devices
+if __name__ == "__main__" and "--no-devices" not in os.sys.argv:
+    # Parity with dryrun: 512 host devices, for the CLI only, so that
+    # importing this module never changes a caller's device count.
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse  # noqa: E402

@@ -75,16 +75,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.engine import get_engine
-from repro.core.icp import scrub_nonfinite
 from repro.core.odometry import (KIND_REGISTER, FrameDiagnostics,
-                                 OdometryConfig, OdometryPipeline)
-from repro.core.transform import transform_points
+                                 OdometryConfig, OdometryPipeline,
+                                 prepare_scan, prepare_scans)
 from repro.data.collate import PAD_SENTINEL, bucket_size, pad_cloud
-from repro.data.submap import (SubmapParams, empty_state, fuse_state,
-                               state_views)
-from repro.data.voxelize import voxel_downsample
+from repro.data.submap import (SubmapParams, empty_state, fuse_lane,
+                               fuse_lanes, outside_lattice_frac,
+                               outside_lattice_fracs, state_views)
 
 
 class ServiceConfig(NamedTuple):
@@ -213,73 +211,6 @@ class _LaneSubmap:
                            "batched fleet round, never inserted per-stream")
 
 
-# -- shared one-lane bodies --------------------------------------------------
-# The single source of the per-lane math, used by BOTH the single-device
-# jits and the sharded (shard_map) factories: one definition means the two
-# modes are bit-identical per lane by construction.
-
-def _prepare_one(pts, valid, voxel, budget):
-    pts, valid = scrub_nonfinite(pts, valid)
-    return voxel_downsample(pts, voxel, max_points=budget, valid=valid)
-
-
-def _lattice_one(T, src, sv, origin, params: SubmapParams):
-    pts = transform_points(T, src)
-    c = jnp.floor((pts - origin) / params.voxel_size)
-    inb = jnp.all((c >= 0) & (c < jnp.asarray(params.dims, jnp.float32)),
-                  axis=-1)
-    n_valid = jnp.maximum(jnp.sum(sv), 1)
-    return jnp.sum(jnp.logical_and(sv, ~inb)) / n_valid
-
-
-def _fuse_one(state, src, sv, pose, acc, params: SubmapParams):
-    """One lane's accept-gated submap fuse on a storage-mode state tuple.
-    Non-accepted lanes pass their state through bit-unchanged (and
-    contribute zero dropped cells); occupancy reports the KEPT state."""
-    world = transform_points(pose, src)
-    fused, occ, dropped = fuse_state(state, world, sv, pose[:3, 3], params)
-    kept = jax.tree_util.tree_map(
-        lambda new, old: jnp.where(acc, new, old), fused, state)
-    occ_kept = jnp.where(acc, occ, jnp.sum(state_views(kept, params)[1]))
-    return kept, occ_kept, jnp.where(acc, dropped, 0)
-
-
-# -- single-device executables ----------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("voxel", "budget"))
-def _prepare_batch(pts_b, valid_b, voxel: float, budget: int):
-    """Vmapped sensor-boundary stage: scrub NaN/Inf rows and voxel-
-    downsample every staged lane in one executable. Returns
-    ``(src_b, sv_b, n_valid_b)`` — each lane bit-identical to the eager
-    per-frame path in ``OdometryPipeline.prepare_frame``."""
-    src_b, sv_b = jax.vmap(
-        lambda p, v: _prepare_one(p, v, voxel, budget))(pts_b, valid_b)
-    return src_b, sv_b, jnp.sum(sv_b, axis=1)
-
-
-@functools.partial(jax.jit, static_argnames=("params",))
-def _lattice_batch(T_b, src_b, sv_b, origin_b, params: SubmapParams):
-    """Vmapped out-of-lattice probe — the batched spelling of
-    ``OdometryPipeline._out_of_lattice_frac`` over every fleet lane."""
-    return jax.vmap(
-        lambda T, s, v, o: _lattice_one(T, s, v, o, params))(
-            T_b, src_b, sv_b, origin_b)
-
-
-@functools.partial(jax.jit, static_argnames=("params",),
-                   donate_argnums=(0,))
-def _fuse_batch(state_b, src_b, sv_b, pose_b, accept_b,
-                params: SubmapParams):
-    """Vmapped submap fuse with per-lane accept select over stacked
-    storage-mode state tuples. The incoming map state is donated — the
-    largest arrays in the service reuse their device allocation in
-    place, the ring-buffer idiom of the on-chip designs this layer
-    mirrors. Returns ``(state_b', occupied_b, dropped_b)``."""
-    return jax.vmap(
-        lambda st, s, v, p, a: _fuse_one(st, s, v, p, a, params))(
-            state_b, src_b, sv_b, pose_b, accept_b)
-
-
 # -- sharded executables (one per mesh + static config, cached) -------------
 
 _SPEC = P("streams")
@@ -289,23 +220,23 @@ _SPEC = P("streams")
 def _sharded_prepare(mesh, voxel: float, budget: int):
     def body(pts_l, valid_l):
         src, sv = jax.vmap(
-            lambda p, v: _prepare_one(p, v, voxel, budget))(pts_l, valid_l)
+            lambda p, v: prepare_scan(p, v, voxel, budget))(pts_l, valid_l)
         return src, sv, jnp.sum(sv, axis=1)
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(_SPEC, _SPEC),
-                             out_specs=(_SPEC, _SPEC, _SPEC),
-                             check_vma=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(_SPEC, _SPEC),
+                                 out_specs=(_SPEC, _SPEC, _SPEC),
+                                 check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded_lattice(mesh, params: SubmapParams):
     def body(T_l, src_l, sv_l, origin_l):
         return jax.vmap(
-            lambda T, s, v, o: _lattice_one(T, s, v, o, params))(
+            lambda T, s, v, o: outside_lattice_frac(T, s, v, o, params))(
                 T_l, src_l, sv_l, origin_l)
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(_SPEC,) * 4,
-                             out_specs=_SPEC, check_vma=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(_SPEC,) * 4,
+                                 out_specs=_SPEC, check_vma=False))
 
 
 def _state_spec(params: SubmapParams) -> tuple:
@@ -318,13 +249,13 @@ def _sharded_fuse(mesh, params: SubmapParams):
 
     def body(state_l, src_l, sv_l, pose_l, acc_l):
         return jax.vmap(
-            lambda st, s, v, p, a: _fuse_one(st, s, v, p, a, params))(
+            lambda st, s, v, p, a: fuse_lane(st, s, v, p, a, params))(
                 state_l, src_l, sv_l, pose_l, acc_l)
 
-    return jax.jit(shard_map(body, mesh=mesh,
-                             in_specs=(sspec, _SPEC, _SPEC, _SPEC, _SPEC),
-                             out_specs=(sspec, _SPEC, _SPEC),
-                             check_vma=False),
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(sspec, _SPEC, _SPEC, _SPEC, _SPEC),
+                                 out_specs=(sspec, _SPEC, _SPEC),
+                                 check_vma=False),
                    donate_argnums=(0,))
 
 
@@ -340,9 +271,9 @@ def _sharded_target_views(mesh, params: SubmapParams):
             lambda st: state_views(st, params))(state_l)
         return pts, valid
 
-    return jax.jit(shard_map(body, mesh=mesh,
-                             in_specs=(_state_spec(params),),
-                             out_specs=(_SPEC, _SPEC), check_vma=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(_state_spec(params),),
+                                 out_specs=(_SPEC, _SPEC), check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
@@ -628,7 +559,7 @@ class RegistrationService:
                                else self._idle_pts for i in range(S)])
             valid_b = jnp.stack([work[i][1].valid if i in work
                                  else self._idle_valid for i in range(S)])
-            src_b, sv_b, nv_b = _prepare_batch(pts_b, valid_b,
+            src_b, sv_b, nv_b = prepare_scans(pts_b, valid_b,
                                                odo.scan_voxel,
                                                odo.scan_budget)
         n_valid = np.asarray(nv_b)
@@ -681,7 +612,7 @@ class RegistrationService:
                 probe = _sharded_lattice(self._mesh, odo.submap)
                 lat_b = probe(res.T, src_b, sv_b, origin_b)
             else:
-                lat_b = _lattice_batch(res.T, src_b, sv_b, origin_b,
+                lat_b = outside_lattice_fracs(res.T, src_b, sv_b, origin_b,
                                        odo.submap)
             res_host, lat_host = jax.device_get((res, lat_b))
 
@@ -737,7 +668,7 @@ class RegistrationService:
                         dropped_cells=view.dropped_cells)
                     outputs[stream.id] = (pose, diag)
             else:
-                state_b, occ_b, drop_b = _fuse_batch(
+                state_b, occ_b, drop_b = fuse_lanes(
                     self._stack_states(work, S),
                     jnp.stack([fuse_reqs[i].src if i in fuse_reqs
                                else src_b[i] for i in range(S)]),

@@ -13,6 +13,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro.compat import make_mesh  # noqa: E402
 from repro.core import ICPParams, icp_fixed_iterations  # noqa: E402
 from repro.core.distributed import (batched_icp_sharded,  # noqa: E402
                                     distributed_nn_search, icp_sharded,
@@ -24,7 +25,7 @@ from repro.core.transform import (random_rigid_transform,  # noqa: E402
 
 def main():
     assert jax.device_count() == 8, jax.devices()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
 
     key = jax.random.PRNGKey(0)
 

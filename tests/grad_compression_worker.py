@@ -14,7 +14,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro.compat import make_mesh, shard_map  # noqa: E402
+from repro.compat import make_mesh  # noqa: E402
 from repro.optim.compression import (compressed_grad_reduce,  # noqa: E402
                                      init_error_feedback)
 
@@ -30,10 +30,10 @@ def main():
         out, new_ef = compressed_grad_reduce({"g": g}, "data", {"g": ef})
         return out["g"], new_ef["g"]
 
-    fn = shard_map(reduce_once, mesh=mesh,
-                   in_specs=(P("data"), P("data")), out_specs=(P("data"),
+    fn = jax.shard_map(reduce_once, mesh=mesh,
+                       in_specs=(P("data"), P("data")), out_specs=(P("data"),
                                                                P("data")),
-                   check_vma=False)
+                       check_vma=False)
     g_in = gs.reshape(8000)
     out, ef = fn(g_in, jnp.zeros(8000))
     exact = jnp.mean(gs, axis=0)
@@ -78,7 +78,7 @@ def main():
         return w, ef
 
     def run(compress):
-        step = shard_map(
+        step = jax.shard_map(
             functools.partial(dp_step, compress=compress), mesh=mesh,
             in_specs=(P(), {"a": P(), "b": P()}, P("data"), P("data")),
             out_specs=(P(), {"a": P(), "b": P()}), check_vma=False)

@@ -14,6 +14,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro.compat import make_mesh  # noqa: E402
 from repro.core import ICPParams, get_engine, icp_fixed_iterations  # noqa: E402
 from repro.core.distributed import (batched_icp_sharded,  # noqa: E402
                                     shard_inputs, stream_sharded_icp,
@@ -156,7 +157,7 @@ def main():
     print("stream_sharded_icp D=8 == per-lane single device OK")
 
     # --- legacy point-sharded path vs the xla engine (2-device mesh) ------
-    mesh2 = jax.make_mesh((2, 1), ("data", "model"))
+    mesh2 = make_mesh((2, 1), ("data", "model"))
     sb, db = shard_inputs(mesh2, src_b[:4], dst_b[:4])
     res_leg = batched_icp_sharded(mesh2, sb, db, params,
                                   frame_axes=("data",),

@@ -18,7 +18,8 @@ def test_dryrun_qwen2_decode_cell(tmp_path):
          "--shape", "decode_32k", "--mesh", "single",
          "--out-dir", str(tmp_path)],
         capture_output=True, text=True, timeout=900,
-        env={**os.environ, "PYTHONPATH": str(ROOT / "src")},
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "PYTHONPATH": str(ROOT / "src")},
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     rec = json.loads((tmp_path / "qwen2-0.5b__decode_32k__single.json"
@@ -39,7 +40,8 @@ def test_dryrun_icp_cell_multi_pod(tmp_path):
          "--shape", "fleet_130k", "--mesh", "multi",
          "--out-dir", str(tmp_path)],
         capture_output=True, text=True, timeout=900,
-        env={**os.environ, "PYTHONPATH": str(ROOT / "src")},
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "PYTHONPATH": str(ROOT / "src")},
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     rec = json.loads((tmp_path / "fpps-icp__fleet_130k__multi.json"

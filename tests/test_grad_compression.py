@@ -14,7 +14,8 @@ def test_grad_compression_subprocess():
     proc = subprocess.run(
         [sys.executable, str(ROOT / "tests" / "grad_compression_worker.py")],
         capture_output=True, text=True, timeout=600,
-        env={**os.environ, "PYTHONPATH": str(ROOT / "src")},
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "PYTHONPATH": str(ROOT / "src")},
     )
     assert proc.returncode == 0, f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"
     assert "GRAD-COMPRESSION-OK" in proc.stdout

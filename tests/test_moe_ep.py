@@ -14,7 +14,8 @@ def test_moe_ep_subprocess():
     proc = subprocess.run(
         [sys.executable, str(ROOT / "tests" / "moe_ep_worker.py")],
         capture_output=True, text=True, timeout=600,
-        env={**os.environ, "PYTHONPATH": str(ROOT / "src")},
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "PYTHONPATH": str(ROOT / "src")},
     )
     assert proc.returncode == 0, f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"
     assert "MOE-EP-OK" in proc.stdout

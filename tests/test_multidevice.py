@@ -16,7 +16,8 @@ def test_sharded_service_multidevice_subprocess():
     proc = subprocess.run(
         [sys.executable, str(ROOT / "tests" / "multidevice_worker.py")],
         capture_output=True, text=True, timeout=900,
-        env={**__import__("os").environ, "PYTHONPATH": str(ROOT / "src")},
+        env={**__import__("os").environ, "JAX_PLATFORMS": "cpu",
+             "PYTHONPATH": str(ROOT / "src")},
     )
     assert proc.returncode == 0, f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"
     assert "MULTIDEVICE-OK" in proc.stdout

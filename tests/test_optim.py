@@ -73,8 +73,7 @@ def test_analyzer_counts_scan_trip_counts():
     assert abs(m.dot_flops - expect) / expect < 0.01
     assert m.unknown_trip_whiles == 0
     # naive cost_analysis must NOT match (documents why the analyzer exists)
-    from repro.compat import cost_analysis
-    naive = cost_analysis(compiled)["flops"]
+    naive = compiled.cost_analysis()["flops"]
     assert naive < expect / 2
 
 

@@ -8,13 +8,15 @@ service's behaviour, not per-test compilation. ``recovery=False`` keeps
 the control plane on the legacy accept guard: no cascade tiers means no
 extra per-tier engines compile inside the tests.
 """
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import ICPParams
 from repro.core.odometry import OdometryConfig, OdometryPipeline
 from repro.data.pointcloud import SceneConfig, sequence_scans
-from repro.data.submap import SubmapParams
+from repro.data.submap import (Submap, SubmapParams, empty_state,
+                               fuse_lanes)
 from repro.serve.registration_service import (RegistrationService,
                                               ServiceConfig)
 
@@ -75,6 +77,30 @@ def test_service_matches_standalone_pipeline_bitwise():
             np.testing.assert_array_equal(np.asarray(pose_svc),
                                           np.asarray(pose_ref))
             assert diag_svc == diag_ref
+
+
+def test_standalone_map_fuse_matches_fleet_fuse_bitwise():
+    """A standalone pipeline fuses its map with ``Submap.insert_scan`` at
+    lane 0 of the fleet's lane count; the service fuses every stream in
+    one batched call. Lane 0 does not depend on what the other lanes
+    hold, so the maps agree bit for bit."""
+    params = ODO.submap
+    rng = np.random.default_rng(3)
+    scans = rng.uniform(-10, 10, (2, 256, 3)).astype(np.float32)
+    valid = np.ones((2, 256), bool)
+    poses = np.stack([np.eye(4, dtype=np.float32)] * 2)
+    a = 0.3
+    poses[0, :3, :3] = [[np.cos(a), -np.sin(a), 0],
+                        [np.sin(a), np.cos(a), 0], [0, 0, 1]]
+    poses[:, :3, 3] = [[1.7, -0.4, 0.05], [-3.0, 2.0, 0.0]]
+    sub = Submap(params)
+    sub.insert_scan(scans[0], poses[0], valid[0], lanes=2)
+    fleet, _, _ = fuse_lanes(
+        tuple(jnp.stack([leaf, leaf]) for leaf in empty_state(params)),
+        jnp.asarray(scans), jnp.asarray(valid), jnp.asarray(poses),
+        jnp.array([True, True]), params)
+    for mine, lanes in zip(sub.state, fleet):
+        np.testing.assert_array_equal(np.asarray(mine), np.asarray(lanes[0]))
 
 
 # -- retrace avoidance -----------------------------------------------------

@@ -118,7 +118,7 @@ TRACING_WRAPPERS = {
     "jax.experimental.pallas.pallas_call": (0,),
     "jax.experimental.shard_map.shard_map": (0,),
     "jax.sharding.shard_map": (0,),
-    "repro.compat.shard_map": (0,),
+    "jax.shard_map": (0,),
 }
 
 # jax.* callables whose *result* lives on the host (never a tracer).
