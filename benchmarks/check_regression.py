@@ -6,7 +6,7 @@ the committed baselines, fail loudly on a >20% regression.
 
 Baselines are the committed ``BENCH_nn.json`` / ``BENCH_throughput.json``
 / ``BENCH_odometry.json`` / ``BENCH_robustness.json`` /
-``BENCH_service.json`` at the repo root. The guard re-measures in quick
+``BENCH_scaleout.json`` at the repo root. The guard re-measures in quick
 mode (small scenes, so it finishes in CI minutes) and compares only
 metrics that are *comparable* across the two configurations:
 
@@ -42,7 +42,6 @@ NN_BASELINE = REPO_ROOT / "BENCH_nn.json"
 THROUGHPUT_BASELINE = REPO_ROOT / "BENCH_throughput.json"
 ODOMETRY_BASELINE = REPO_ROOT / "BENCH_odometry.json"
 ROBUSTNESS_BASELINE = REPO_ROOT / "BENCH_robustness.json"
-SERVICE_BASELINE = REPO_ROOT / "BENCH_service.json"
 SCALEOUT_BASELINE = REPO_ROOT / "BENCH_scaleout.json"
 DEFAULT_TOLERANCE = 0.20
 # Median-of-N for timed ratio metrics (see module docstring). Absolute /
@@ -232,47 +231,6 @@ def check_robustness(guard: Guard) -> None:
                         fam["drift_improvement"])
 
 
-def check_service(guard: Guard) -> None:
-    from benchmarks import service_throughput
-
-    baseline = json.loads(SERVICE_BASELINE.read_text())
-    s_max = max(baseline["streams"])
-
-    def measure() -> dict:
-        # Max-stream-count config only (the sweep's smaller fleets are
-        # trend rows, not guarded metrics) so a repeat costs seconds
-        # after the shared first-compile.
-        service_throughput.run(
-            streams=(s_max,), frames=baseline["frames"],
-            warm=baseline["warm"], iters=baseline["iters"],
-            budget=baseline["scan_budget"],
-            out_json=str(REPO_ROOT / "BENCH_service_guard.json"))
-        return json.loads(
-            (REPO_ROOT / "BENCH_service_guard.json").read_text())
-
-    runs = [measure() for _ in range(TIMED_REPEATS)]
-    # Aggregate-fps ratio is same-process (service and sequential loop
-    # measured back-to-back), but its sequential denominator is a
-    # dispatch-dominated per-frame loop with the same run-to-run swing
-    # as throughput/batched_speedup — same wide band, same rationale.
-    guard.ratio("service/fps_ratio",
-                _median(runs, lambda r: r["fps_ratio"]),
-                baseline["fps_ratio"], tolerance=0.5)
-    # p99 ratio: LOWER is better (service round time vs sequential call
-    # time), so it is an absolute ceiling, not a floor. A p99 over 12
-    # rounds is a max-like statistic — one scheduler tick doubles it —
-    # hence the 2x headroom over the committed baseline.
-    guard.absolute("service/p99_latency_ratio",
-                   _median(runs, lambda r: r["p99_latency_ratio"]),
-                   2.0 * baseline["p99_latency_ratio"])
-    # Hard structural contracts, not trends: zero retraces after warmup
-    # and bit-exact parity with the standalone pipeline.
-    guard.absolute("service/retraces_after_warmup",
-                   float(runs[0]["retraces_after_warmup"]), 0.0)
-    guard.absolute("service/parity_max_abs",
-                   runs[0]["parity_max_abs"], 0.0)
-
-
 def check_device_sweep(guard: Guard) -> None:
     from benchmarks import device_sweep
 
@@ -289,13 +247,13 @@ def check_device_sweep(guard: Guard) -> None:
     scaling = (current["sweep"][str(d_hi)]["aggregate_fps"]
                / current["sweep"][str(d_lo)]["aggregate_fps"])
     # Weak-scaling retention is same-process fps(D=8)/fps(D=1); its D=1
-    # denominator is the same dispatch-dominated per-round regime as
-    # service/fps_ratio's sequential loop — same wide band.
+    # denominator is a dispatch-dominated per-round regime that swings
+    # run to run — hence the wide band.
     guard.ratio("scaleout/scaling_x", scaling, baseline["scaling_x"],
                 tolerance=0.5)
     # The fleet-batching headline: one fused round vs the eager
     # per-stream loop on the same 8-stream workload (dispatch-dominated
-    # denominator again — same band as service/fps_ratio).
+    # denominator again — same wide band).
     guard.ratio("scaleout/fused_vs_sequential",
                 current["fused_vs_sequential_x"],
                 baseline["fused_vs_sequential_x"], tolerance=0.5)
@@ -322,7 +280,7 @@ def main(argv=None) -> int:
                     help="allowed fractional regression (default 0.20)")
     ap.add_argument("--only",
                     choices=["nn", "throughput", "odometry", "robustness",
-                             "service", "device_sweep"],
+                             "device_sweep"],
                     default=None)
     args = ap.parse_args(argv)
     guard = Guard(args.tolerance)
@@ -334,8 +292,6 @@ def main(argv=None) -> int:
         check_odometry(guard)
     if args.only in (None, "robustness"):
         check_robustness(guard)
-    if args.only in (None, "service"):
-        check_service(guard)
     if args.only in (None, "device_sweep"):
         check_device_sweep(guard)
     ok = guard.report()

@@ -17,9 +17,6 @@
                                          writes BENCH_odometry.json)
   robustness -> robustness           (fault matrix x recovery cascade
                                          ON/OFF; writes BENCH_robustness.json)
-  service -> service_throughput      (multi-stream fleet rounds vs the
-                                         sequential per-stream loop;
-                                         writes BENCH_service.json)
   scaleout -> device_sweep           (sharded fleet over a forced 8-device
                                          host platform, run in a subprocess;
                                          writes BENCH_scaleout.json)
@@ -37,7 +34,7 @@ from benchmarks import (convergence, device_sweep, kernel_resources,
                         nn_sweep, odometry_drift, power_efficiency,
                         registration_accuracy, registration_latency,
                         registration_throughput, robustness,
-                        roofline_report, service_throughput)
+                        roofline_report)
 from benchmarks.common import QUICK_SCENE, emit
 from repro.compile_cache import enable_compile_cache
 
@@ -52,7 +49,6 @@ SUITES = {
     "convergence": convergence.run,
     "odometry": odometry_drift.run,
     "robustness": robustness.run,
-    "service": service_throughput.run,
     # run_harness respawns the sweep in a subprocess: this process's jax
     # is already initialised with 1 device, the sweep needs a forced 8.
     "scaleout": device_sweep.run_harness,
@@ -65,7 +61,6 @@ QUICK_KWARGS = {
     "table4": dict(n_seqs=2, samples=512, iters=10, scene=QUICK_SCENE),
     "power": dict(n_seqs=2, samples=512, iters=10, scene=QUICK_SCENE),
     "throughput": dict(quick=True),
-    "service": dict(quick=True),
     "scaleout": dict(quick=True),
 }
 # Suites whose smoke mode is a different entry point, not just kwargs.

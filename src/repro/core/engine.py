@@ -115,6 +115,14 @@ class RegistrationEngine:
     def _note_trace(self, kind: str, params: ICPParams, *shapes) -> None:
         self._traces.append((kind, params, shapes))
 
+    def _jit(self, run: Callable, kind: str):
+        """``jax.jit(run)`` under the module name ``jit_<engine>_<kind>``,
+        so a profiler trace tells this engine's executables apart from
+        the slot engines' batch executable, which keeps ``jit_run``."""
+        run.__name__ = run.__qualname__ = (
+            f"{self.name}_{kind}".replace("-", "_"))
+        return jax.jit(run)
+
     def _build_single(self, params: ICPParams):
         nn_fn = self._nn_fn(params)
 
@@ -123,7 +131,7 @@ class RegistrationEngine:
             return icp(src, dst, params, T0, nn_fn=nn_fn,
                        src_valid=sv, dst_valid=dv)
 
-        return jax.jit(run)
+        return self._jit(run, "single")
 
     def _build_batch(self, params: ICPParams):
         nn_fn = self._nn_fn(params)
@@ -133,7 +141,7 @@ class RegistrationEngine:
             return icp_batch(src_b, dst_b, params, T0, nn_fn=nn_fn,
                              src_valid=sv, dst_valid=dv)
 
-        return jax.jit(run)
+        return self._jit(run, "batch")
 
     def _executable(self, kind: str, params: ICPParams):
         key = (kind, params)
@@ -303,7 +311,7 @@ class PallasEngine(RegistrationEngine):
             return icp(src, dst, params, T0, nn_fn=nn_fn, src_valid=sv,
                        target_normals=normals)
 
-        return jax.jit(run)
+        return self._jit(run, "single")
 
     def _build_batch(self, params: ICPParams):
         from repro.kernels.ops import resident_nn_fn
@@ -334,7 +342,7 @@ class PallasEngine(RegistrationEngine):
 
             return jax.vmap(one)(src_b, dst_b, T0, sv, dv)
 
-        return jax.jit(run)
+        return self._jit(run, "batch")
 
 
 class DistributedEngine(RegistrationEngine):
@@ -417,7 +425,7 @@ class DistributedEngine(RegistrationEngine):
                 res = jax.tree_util.tree_map(lambda x: x[:b], res)
             return res
 
-        return jax.jit(run)
+        return self._jit(run, "batch")
 
     def _build_single(self, params: ICPParams):
         batch_fn = self._build_batch(params)
@@ -472,6 +480,8 @@ class SlotEngine(RegistrationEngine):
 
             return jax.vmap(one)(src_b, dst_b, T0, sv, dv)
 
+        # module name ``jit_run``: the name a trace reads the slot
+        # executable by
         return jax.jit(run)
 
     def register(self, source, target, params: ICPParams | None = None,
@@ -573,6 +583,8 @@ class ShardedSlotEngine(SlotEngine):
                                       src_valid=sv, dst_valid=dv,
                                       nn_fn=nn_fn)
 
+        # module name ``jit_run``: the name a trace reads the slot
+        # executable by
         return jax.jit(run)
 
 

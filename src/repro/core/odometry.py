@@ -62,6 +62,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.engine import get_engine
 from repro.core.health import (FAILED, OK, SUSPECT, HealthThresholds,
@@ -345,8 +346,9 @@ class OdometryPipeline:
         frame): conditioning of its 6x6 plane system."""
         if not self.config.observability_probe:
             return None
-        A = np.asarray(_scan_plane_system(src, sv, NormalParams()),
-                       np.float64)
+        system = _scan_plane_system(src, sv, NormalParams())
+        with TraceAnnotation("fpps.wait.scan_probe"):
+            A = np.asarray(system, np.float64)
         return normal_equation_condition(A)
 
     # -- recovery tiers ----------------------------------------------------
@@ -409,9 +411,11 @@ class OdometryPipeline:
                 return res, health, 0
             attempts.append((0, res, health))
         for tier, name in enumerate(cfg.recovery_tiers, start=1):
-            r = self._tier_attempt(name, src, sv, map_pts, map_valid, T0)
-            h = self._assess(r, T0, src, sv, condition,
-                             trust_prediction=not reacquire)
+            with TraceAnnotation("fpps.tier", tier=name):
+                r = self._tier_attempt(name, src, sv, map_pts, map_valid,
+                                       T0)
+                h = self._assess(r, T0, src, sv, condition,
+                                 trust_prediction=not reacquire)
             if h.ok:
                 return r, h, tier
             attempts.append((tier, r, h))
@@ -532,11 +536,12 @@ class OdometryPipeline:
             reacquire = prep.reacquire
             if cfg.recovery and frame >= cfg.warmup_frames:
                 condition = self._scan_condition(src, sv)
-                map_pts, map_valid = self.submap.target()
-                res, health, tier = self._cascade(
-                    src, sv, map_pts, map_valid, T0, condition,
-                    reacquire=reacquire, primary=result,
-                    out_of_lattice=lattice_frac)
+                with TraceAnnotation("fpps.cascade"):
+                    map_pts, map_valid = self.submap.target()
+                    res, health, tier = self._cascade(
+                        src, sv, map_pts, map_valid, T0, condition,
+                        reacquire=reacquire, primary=result,
+                        out_of_lattice=lattice_frac)
                 accepted = res is not None
             else:
                 res = result

@@ -249,7 +249,7 @@ class PyramidEngine(RegistrationEngine):
             return icp_pyramid(src, dst, params, initial_transform=T0,
                                src_valid=sv, dst_valid=dv, **kw)
 
-        return jax.jit(run)
+        return self._jit(run, "single")
 
     def _build_batch(self, params: ICPParams):
         kw = self._pyramid_kwargs()
@@ -270,7 +270,7 @@ class PyramidEngine(RegistrationEngine):
 
             return jax.vmap(one)(src_b, dst_b, T0, sv, dv)
 
-        return jax.jit(run)
+        return self._jit(run, "batch")
 
 
 register_engine("pyramid", PyramidEngine)

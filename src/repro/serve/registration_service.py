@@ -52,6 +52,12 @@ bit-for-bit (weak-scaling parity — see ``ShardedSlotEngine``; across
 *different* block widths agreement is fp-tolerance, since XLA may tile a
 lane's point-axis reductions differently).
 
+Observability: every public call and each stage of a round runs inside
+a named ``jax.profiler.TraceAnnotation`` span (``fpps.*``; free when no
+profiler runs), and ``service_report()`` counts the slot executable's
+lane-iterations — asked for, and computed while the slowest lane of a
+block kept the vmapped loop running.
+
 Typical use::
 
     svc = RegistrationService(ServiceConfig(slots=8))      # single-device
@@ -73,6 +79,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.engine import get_engine
@@ -316,9 +323,10 @@ class RegistrationService:
         cap = bucket_size(config.scan_capacity)
         self.config = config._replace(scan_capacity=cap)
         self._sharded = config.devices is not None
+        self._devices = int(config.devices) if self._sharded else 1
         sp = self.stream_config.submap
         if self._sharded:
-            D = int(config.devices)
+            D = self._devices
             if D < 1 or D > jax.device_count():
                 raise ValueError(f"devices must be in "
                                  f"[1, {jax.device_count()}], got {D}")
@@ -357,6 +365,11 @@ class RegistrationService:
         self.frames_processed = 0
         self.frames_dropped = 0
         self.cascade_escapes = 0
+        # lane-iteration counters of the slot executable (see
+        # ``service_report``)
+        self.lane_iterations = 0
+        self.lane_iterations_run = 0
+        self.loop_iterations = 0
         self._eye = np.eye(4, dtype=np.float32)
 
     @property
@@ -385,9 +398,9 @@ class RegistrationService:
         if not self._sharded:
             return next((i for i, s in enumerate(self._slots) if s is None),
                         None)
-        L = self.config.slots // int(self.config.devices)
+        L = self.config.slots // self._devices
         best = None
-        for d in range(int(self.config.devices)):
+        for d in range(self._devices):
             block = self._slots[d * L:(d + 1) * L]
             free = next((d * L + i for i, s in enumerate(block)
                          if s is None), None)
@@ -410,6 +423,10 @@ class RegistrationService:
         (``admission="queue"``); raises RuntimeError when the fleet is
         full under ``admission="reject"``. Frames may be submitted while
         queued — they stage and wait."""
+        with TraceAnnotation("fpps.admit", stream=stream_id):
+            return self._admit(stream_id)
+
+    def _admit(self, stream_id: str) -> bool:
         if stream_id in self._streams:
             raise ValueError(f"stream {stream_id!r} already admitted")
         stream = self._make_stream(stream_id)
@@ -434,6 +451,10 @@ class RegistrationService:
         (counted as dropped). In sharded mode the lane's resident map
         state is reset to idle in place — the next stream bound to this
         slot must never see its predecessor's map."""
+        with TraceAnnotation("fpps.close", stream=stream_id):
+            return self._close(stream_id)
+
+    def _close(self, stream_id: str) -> StreamReport:
         stream = self._streams.pop(stream_id)
         stream.dropped += len(stream.queue)
         self.frames_dropped += len(stream.queue)
@@ -488,6 +509,10 @@ class RegistrationService:
         (``drop_policy="newest"``). Dropping the *oldest* staged frame
         still returns True — the submitted frame survived, an older one
         paid."""
+        with TraceAnnotation("fpps.submit", stream=stream_id):
+            return self._submit(stream_id, scan, valid)
+
+    def _submit(self, stream_id: str, scan, valid) -> bool:
         stream = self._streams[stream_id]
         padded, pvalid = self.stage_scan(scan, valid)
         if self._sharded:
@@ -526,11 +551,19 @@ class RegistrationService:
         processed this round. Streams with empty queues idle at zero
         marginal device cost (their lanes are mask-dead). In sharded
         mode every stage runs inside the shard body over the streams
-        mesh; the structure is identical."""
-        cfg = self.config
-        odo = self.stream_config
-        S = cfg.slots
-        sharded = self._sharded
+        mesh; the structure is identical.
+
+        The round and each of its stages run inside named profiler spans
+        (``fpps.step`` with ``round=``, then ``fpps.prepare``,
+        ``fpps.classify``, ``fpps.register``, one ``fpps.complete`` per
+        stream with ``stream=`` and ``frame=``, ``fpps.fuse``); each
+        blocking device->host fetch sits in a ``fpps.wait.<stage>``
+        child span, so a trace separates the host waiting on the chip
+        from host work while the chip idles."""
+        with TraceAnnotation("fpps.step", round=self.rounds):
+            return self._step()
+
+    def _step(self) -> dict:
         work = {}
         for lane, sid in enumerate(self._slots):
             if sid is None:
@@ -543,7 +576,65 @@ class RegistrationService:
         self.rounds += 1
 
         # 1. staged-scan stack -> vmapped scrub + downsample (data plane)
-        if sharded:
+        with TraceAnnotation("fpps.prepare"):
+            src_b, sv_b, n_valid = self._prepare(work)
+
+        # 2. host classification: which lanes register this round
+        with TraceAnnotation("fpps.classify"):
+            preps = {}
+            for lane, (stream, _) in work.items():
+                preps[lane] = stream.pipe.prepare_frame(
+                    None, downsampled=(src_b[lane], sv_b[lane],
+                                       int(n_valid[lane])))
+            reg_lanes = [lane for lane, p in preps.items()
+                         if p.kind == KIND_REGISTER and not p.skip_primary]
+
+        # 3-4. one fleet registration through the slot executable, the
+        #      batched health probe and ONE bulk device->host fetch
+        res_host = lat_host = None
+        if reg_lanes:
+            with TraceAnnotation("fpps.register"):
+                res_host, lat_host = self._register(work, preps, reg_lanes,
+                                                    src_b, sv_b)
+
+        # 5. host control plane: per-stream completion (cascade, accept,
+        #    quarantine) with the fuse deferred into one batched call
+        outputs = {}
+        fuse_reqs = {}
+        for lane, (stream, _) in work.items():
+            prep = preps[lane]
+            with TraceAnnotation("fpps.complete", stream=stream.id,
+                                 frame=prep.frame):
+                if lane in reg_lanes:
+                    lane_res = jax.tree_util.tree_map(lambda x: x[lane],
+                                                      res_host)
+                    lat = float(lat_host[lane])
+                else:
+                    lane_res, lat = None, None
+                pose, diag, fuse_req = stream.pipe.complete_frame(
+                    prep, lane_res, lattice_frac=lat, defer_fuse=True,
+                    defer_bootstrap=self._sharded)
+            if prep.kind == KIND_REGISTER and diag.recovery_tier > 0:
+                stream.cascade_escapes += 1
+                self.cascade_escapes += 1
+            if fuse_req is not None:
+                fuse_reqs[lane] = fuse_req
+            outputs[stream.id] = (pose, diag)
+            self.frames_processed += 1
+
+        # 6. one vmapped fuse over the fleet's submaps (donated buffers)
+        if fuse_reqs:
+            with TraceAnnotation("fpps.fuse"):
+                self._fuse(work, fuse_reqs, outputs, src_b, sv_b)
+        return outputs
+
+    def _prepare(self, work: dict):
+        """Stack the round's staged scans (idle lanes get the filler) and
+        run the vmapped scrub + downsample; returns ``(src_b, sv_b,
+        n_valid)`` with ``n_valid`` fetched to the host."""
+        odo = self.stream_config
+        S = self.config.slots
+        if self._sharded:
             pts_b = jax.device_put(
                 np.stack([work[i][1].pts if i in work else self._idle_pts
                           for i in range(S)]), self._sharding)
@@ -562,140 +653,128 @@ class RegistrationService:
             src_b, sv_b, nv_b = prepare_scans(pts_b, valid_b,
                                                odo.scan_voxel,
                                                odo.scan_budget)
-        n_valid = np.asarray(nv_b)
+        with TraceAnnotation("fpps.wait.prepare"):
+            n_valid = np.asarray(nv_b)
+        return src_b, sv_b, n_valid
 
-        # 2. host classification: which lanes register this round
-        preps = {}
-        for lane, (stream, _) in work.items():
-            preps[lane] = stream.pipe.prepare_frame(
-                None, downsampled=(src_b[lane], sv_b[lane],
-                                   int(n_valid[lane])))
-        reg_lanes = [lane for lane, p in preps.items()
-                     if p.kind == KIND_REGISTER and not p.skip_primary]
-
-        res_host = lat_host = None
-        if reg_lanes:
-            # 3. one fleet registration through the slot executable
-            active = np.zeros((S,), bool)
-            active[reg_lanes] = True
-            if sharded:
-                active_d = jax.device_put(active, self._sharding)
-                sub = self.stream_config.submap
-                if sub.storage == "fp32":
-                    dst_b, dv_b = self._fleet[0], self._fleet[1]
-                else:
-                    views = _sharded_target_views(self._mesh, sub)
-                    dst_b, dv_b = views(self._fleet)
-                origin_b = self._fleet[-1]
+    def _register(self, work: dict, preps: dict, reg_lanes: list, src_b,
+                  sv_b):
+        """Register ``reg_lanes`` in one call of the slot executable, probe
+        the lattice for every lane, and fetch both to the host in one
+        ``device_get``; returns ``(res_host, lat_host)`` and adds the
+        round to the lane-iteration counters."""
+        odo = self.stream_config
+        S = self.config.slots
+        active = np.zeros((S,), bool)
+        active[reg_lanes] = True
+        if self._sharded:
+            active_d = jax.device_put(active, self._sharding)
+            sub = odo.submap
+            if sub.storage == "fp32":
+                dst_b, dv_b = self._fleet[0], self._fleet[1]
             else:
-                active_d = jnp.asarray(active, bool)
-                dst_b = jnp.stack([
-                    work[i][0].pipe.submap.points if i in work
-                    else state_views(self._idle_state, odo.submap)[0]
-                    for i in range(S)])
-                dv_b = jnp.stack([
-                    work[i][0].pipe.submap.valid if i in work
-                    else state_views(self._idle_state, odo.submap)[1]
-                    for i in range(S)])
-                origin_b = jnp.stack([
-                    work[i][0].pipe.submap.origin if i in work
-                    else self._idle_state[-1] for i in range(S)])
-            T0_b = np.stack([preps[i].T0 if i in preps else self._eye
-                             for i in range(S)])
-            res = self.engine.register_batch(
-                src_b, dst_b, odo.params,
-                src_valid=jnp.logical_and(sv_b, active_d[:, None]),
-                dst_valid=jnp.logical_and(dv_b, active_d[:, None]),
-                initial_transforms=T0_b)
-            # 4. batched health probe + ONE bulk device->host fetch
-            if sharded:
-                probe = _sharded_lattice(self._mesh, odo.submap)
-                lat_b = probe(res.T, src_b, sv_b, origin_b)
-            else:
-                lat_b = outside_lattice_fracs(res.T, src_b, sv_b, origin_b,
-                                       odo.submap)
+                views = _sharded_target_views(self._mesh, sub)
+                dst_b, dv_b = views(self._fleet)
+            origin_b = self._fleet[-1]
+        else:
+            active_d = jnp.asarray(active, bool)
+            dst_b = jnp.stack([
+                work[i][0].pipe.submap.points if i in work
+                else state_views(self._idle_state, odo.submap)[0]
+                for i in range(S)])
+            dv_b = jnp.stack([
+                work[i][0].pipe.submap.valid if i in work
+                else state_views(self._idle_state, odo.submap)[1]
+                for i in range(S)])
+            origin_b = jnp.stack([
+                work[i][0].pipe.submap.origin if i in work
+                else self._idle_state[-1] for i in range(S)])
+        T0_b = np.stack([preps[i].T0 if i in preps else self._eye
+                         for i in range(S)])
+        res = self.engine.register_batch(
+            src_b, dst_b, odo.params,
+            src_valid=jnp.logical_and(sv_b, active_d[:, None]),
+            dst_valid=jnp.logical_and(dv_b, active_d[:, None]),
+            initial_transforms=T0_b)
+        if self._sharded:
+            probe = _sharded_lattice(self._mesh, odo.submap)
+            lat_b = probe(res.T, src_b, sv_b, origin_b)
+        else:
+            lat_b = outside_lattice_fracs(res.T, src_b, sv_b, origin_b,
+                                          odo.submap)
+        with TraceAnnotation("fpps.wait.register"):
             res_host, lat_host = jax.device_get((res, lat_b))
+        self._count_iterations(res_host.iterations, reg_lanes)
+        return res_host, lat_host
 
-        # 5. host control plane: per-stream completion (cascade, accept,
-        #    quarantine) with the fuse deferred into one batched call
-        outputs = {}
-        fuse_reqs = {}
-        for lane, (stream, _) in work.items():
-            prep = preps[lane]
-            if lane in reg_lanes:
-                lane_res = jax.tree_util.tree_map(lambda x: x[lane],
-                                                  res_host)
-                lat = float(lat_host[lane])
+    def _count_iterations(self, iterations: np.ndarray,
+                          reg_lanes: list) -> None:
+        """Lane-iteration counters of one slot-executable call, from the
+        fetched per-lane ``ICPResult.iterations``. Each device block's
+        vmapped ``while_loop`` runs until its slowest lane stops, so it
+        computes ``lanes_per_device x`` that lane's iterations whatever
+        the other lanes needed."""
+        lanes = self.config.slots // self._devices
+        slowest = iterations.reshape(self._devices, lanes).max(axis=1)
+        self.lane_iterations += int(iterations[reg_lanes].sum())
+        self.lane_iterations_run += lanes * int(slowest.sum())
+        self.loop_iterations += int(slowest.max())
+
+    def _fuse(self, work: dict, fuse_reqs: dict, outputs: dict, src_b,
+              sv_b) -> None:
+        """One vmapped fuse of the accepted frames into their maps, then
+        the per-lane write-back of map state and diagnostics (amends
+        ``outputs`` in place)."""
+        odo = self.stream_config
+        S = self.config.slots
+        accept = np.zeros((S,), bool)
+        accept[list(fuse_reqs)] = True
+        pose_np = np.stack([fuse_reqs[i].pose if i in fuse_reqs
+                            else self._eye for i in range(S)])
+        mcap = int(odo.submap.capacity)
+        if self._sharded:
+            # the fuse sources ARE this round's prepared batch
+            # (every FuseRequest.src is its lane's src_b slice)
+            fuse = _sharded_fuse(self._mesh, odo.submap)
+            self._fleet, occ_b, drop_b = fuse(
+                self._fleet, src_b, sv_b,
+                jax.device_put(pose_np, self._sharding),
+                jax.device_put(accept, self._sharding))
+        else:
+            state_b, occ_b, drop_b = fuse_lanes(
+                self._stack_states(work, S),
+                jnp.stack([fuse_reqs[i].src if i in fuse_reqs
+                           else src_b[i] for i in range(S)]),
+                jnp.stack([fuse_reqs[i].sv if i in fuse_reqs
+                           else sv_b[i] for i in range(S)]),
+                jnp.asarray(pose_np, jnp.float32),
+                jnp.asarray(accept, bool), odo.submap)
+        with TraceAnnotation("fpps.wait.fuse"):
+            occ, drop = np.asarray(occ_b), np.asarray(drop_b)
+        for lane in fuse_reqs:
+            stream = work[lane][0]
+            sub = stream.pipe.submap
+            if self._sharded:
+                sub._occupied = int(occ[lane])
             else:
-                lane_res, lat = None, None
-            pose, diag, fuse_req = stream.pipe.complete_frame(
-                prep, lane_res, lattice_frac=lat, defer_fuse=True,
-                defer_bootstrap=sharded)
-            if prep.kind == KIND_REGISTER and diag.recovery_tier > 0:
-                stream.cascade_escapes += 1
-                self.cascade_escapes += 1
-            if fuse_req is not None:
-                fuse_reqs[lane] = fuse_req
+                sub.state = tuple(leaf[lane] for leaf in state_b)
+            sub.frames_inserted += 1
+            sub.dropped_cells += int(drop[lane])
+            pose, diag = outputs[stream.id]
+            diag = stream.pipe.amend_diagnostics(
+                diag.frame, map_occupancy=float(occ[lane]) / mcap,
+                dropped_cells=sub.dropped_cells)
             outputs[stream.id] = (pose, diag)
-            self.frames_processed += 1
-
-        # 6. one vmapped fuse over the fleet's submaps (donated buffers)
-        if fuse_reqs:
-            accept = np.zeros((S,), bool)
-            accept[list(fuse_reqs)] = True
-            pose_np = np.stack([fuse_reqs[i].pose if i in fuse_reqs
-                                else self._eye for i in range(S)])
-            mcap = int(odo.submap.capacity)
-            if sharded:
-                # the fuse sources ARE this round's prepared batch
-                # (every FuseRequest.src is its lane's src_b slice)
-                fuse = _sharded_fuse(self._mesh, odo.submap)
-                self._fleet, occ_b, drop_b = fuse(
-                    self._fleet, src_b, sv_b,
-                    jax.device_put(pose_np, self._sharding),
-                    jax.device_put(accept, self._sharding))
-                occ, drop = np.asarray(occ_b), np.asarray(drop_b)
-                for lane, req in fuse_reqs.items():
-                    stream = work[lane][0]
-                    view = stream.pipe.submap
-                    view.frames_inserted += 1
-                    view._occupied = int(occ[lane])
-                    view.dropped_cells += int(drop[lane])
-                    pose, diag = outputs[stream.id]
-                    diag = stream.pipe.amend_diagnostics(
-                        diag.frame,
-                        map_occupancy=float(occ[lane]) / mcap,
-                        dropped_cells=view.dropped_cells)
-                    outputs[stream.id] = (pose, diag)
-            else:
-                state_b, occ_b, drop_b = fuse_lanes(
-                    self._stack_states(work, S),
-                    jnp.stack([fuse_reqs[i].src if i in fuse_reqs
-                               else src_b[i] for i in range(S)]),
-                    jnp.stack([fuse_reqs[i].sv if i in fuse_reqs
-                               else sv_b[i] for i in range(S)]),
-                    jnp.asarray(pose_np, jnp.float32),
-                    jnp.asarray(accept, bool), odo.submap)
-                occ, drop = np.asarray(occ_b), np.asarray(drop_b)
-                for lane, req in fuse_reqs.items():
-                    stream = work[lane][0]
-                    sub = stream.pipe.submap
-                    sub.state = tuple(leaf[lane] for leaf in state_b)
-                    sub.frames_inserted += 1
-                    sub.dropped_cells += int(drop[lane])
-                    pose, diag = outputs[stream.id]
-                    diag = stream.pipe.amend_diagnostics(
-                        diag.frame,
-                        map_occupancy=float(occ[lane]) / mcap,
-                        dropped_cells=sub.dropped_cells)
-                    outputs[stream.id] = (pose, diag)
-        return outputs
 
     def sync(self) -> None:
         """Block until every in-flight device computation for the fleet
         (registration, fuse writebacks) has completed. Outputs returned by
         ``step`` are already host-side; this exists for benchmarks that
         must charge the async fuse tail to the round that issued it."""
+        with TraceAnnotation("fpps.sync"):
+            self._sync()
+
+    def _sync(self) -> None:
         if self._sharded:
             jax.block_until_ready(self._fleet)
             return
@@ -740,9 +819,24 @@ class RegistrationService:
     def service_report(self) -> dict:
         """Fleet-level counters: rounds run, frames processed/dropped,
         cascade escapes, live/pending stream counts, the device count the
-        fleet is sharded over (1 = single-device mode), and the slot
+        fleet is sharded over (1 = single-device mode), the slot
         engine's trace count (constant after warmup = the retrace-free
-        invariant)."""
+        invariant), and the slot executable's lane iterations:
+
+          * ``lane_iterations`` — ICP iterations of the registered lanes,
+            summed over lanes and rounds (the work asked for);
+          * ``lane_iterations_run`` — lane-iterations the executable
+            computed: per round and device block, ``lanes_per_device x``
+            the block's slowest lane (converged and idle lanes ride the
+            loop frozen until it ends);
+          * ``loop_iterations`` — the rounds' trip counts (the slowest
+            lane's iterations), summed;
+          * ``slots`` — the fleet width.
+
+        ``1 - lane_iterations / lane_iterations_run`` is the share of the
+        executable's lane-iterations spent on lanes already converged or
+        idle. All are counted from the fetched results, with no transfer
+        of their own."""
         return {
             "rounds": self.rounds,
             "frames_processed": self.frames_processed,
@@ -750,8 +844,12 @@ class RegistrationService:
             "cascade_escapes": self.cascade_escapes,
             "active_streams": sum(1 for s in self._slots if s is not None),
             "pending_streams": len(self._pending),
-            "devices": (int(self.config.devices) if self._sharded else 1),
+            "devices": self._devices,
             "trace_count": self.engine.trace_count,
+            "lane_iterations": self.lane_iterations,
+            "lane_iterations_run": self.lane_iterations_run,
+            "loop_iterations": self.loop_iterations,
+            "slots": self.config.slots,
         }
 
     def diagnostics(self, stream_id: str) -> list[FrameDiagnostics]:

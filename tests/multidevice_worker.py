@@ -76,6 +76,10 @@ def main():
             assert out8[sid][f][1] == diag_ref, (sid, f)
     rep = svc8.service_report()
     assert rep["devices"] == 8 and rep["frames_processed"] == 30
+    # one while_loop per one-lane device block: each block runs only as
+    # long as its own lane, never to the fleet's slowest
+    assert (rep["lane_iterations"] <= rep["lane_iterations_run"]
+            <= rep["slots"] * rep["loop_iterations"])
     print("sharded service D=8 == single-device reference OK")
 
     # --- D=8 vs a D=1 8-lane service: fp-tolerance agreement --------------
