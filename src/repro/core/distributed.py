@@ -44,6 +44,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.icp import ICPParams, ICPResult, icp, icp_fixed_iterations
 from repro.core.nn_search import nn_search
+from repro.data.voxelize import VoxelMap
 
 
 # -- stream sharding (the production scale-out path) ------------------------
@@ -78,7 +79,9 @@ def stream_sharded_icp(mesh: Mesh, src_b: jax.Array, dst_b: jax.Array,
     size serving the same lanes-per-device block width (the weak-scaling
     parity contract the sharded service's tests assert). Masks/warm
     starts default to all-ones / identity; ``nn_fn`` swaps the
-    correspondence searcher exactly as in ``core.icp.icp``.
+    correspondence searcher exactly as in ``core.icp.icp``, and a
+    :class:`~repro.data.voxelize.VoxelMap` batch of targets is searched
+    lane by lane through its lattice (``core.engine.slot_lane``).
 
     Call it inside ``jax.jit`` for one fused executable (the
     ``sharded-slots`` engine does); inputs not already placed with
@@ -94,20 +97,20 @@ def stream_sharded_icp(mesh: Mesh, src_b: jax.Array, dst_b: jax.Array,
             jnp.eye(4, dtype=src_b.dtype), (S, 4, 4))
     if src_valid is None:
         src_valid = jnp.ones(src_b.shape[:2], bool)
+    on_lattice = isinstance(dst_b, VoxelMap)
     if dst_valid is None:
-        dst_valid = jnp.ones(dst_b.shape[:2], bool)
+        points = dst_b.points if on_lattice else dst_b
+        dst_valid = jnp.ones(points.shape[:2], bool)
 
-    def body(src_l, dst_l, T0_l, sv_l, dv_l):
-        def one(src, dst, T0, sv, dv):
-            return icp(src, dst, params, T0, nn_fn=nn_fn,
-                       src_valid=sv, dst_valid=dv)
-        return jax.vmap(one)(src_l, dst_l, T0_l, sv_l, dv_l)
-
+    from repro.core.engine import slot_lane
     spec = P("streams")
+    # a voxel map's points and origins shard with their lanes
     out_specs = ICPResult(T=spec, rmse=spec, iterations=spec,
-                          converged=spec, inlier_frac=spec, degenerate=spec)
-    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 5,
-                       out_specs=out_specs, check_vma=False)
+                          converged=spec, inlier_frac=spec, degenerate=spec,
+                          lattice_overflow=spec if on_lattice else None)
+    fn = jax.shard_map(jax.vmap(slot_lane(params, nn_fn)), mesh=mesh,
+                       in_specs=(spec,) * 5, out_specs=out_specs,
+                       check_vma=False)
     return fn(src_b, dst_b, initial_transforms, src_valid, dst_valid)
 
 

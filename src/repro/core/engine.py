@@ -30,6 +30,7 @@ Typical use::
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Callable
 
 import jax
@@ -38,7 +39,14 @@ import numpy as np
 
 from repro.core.icp import (ICPParams, ICPResult, icp, icp_batch,
                             icp_fixed_iterations, scrub_nonfinite)
+from repro.core.nn_search_grid import lattice_nn_fn
 from repro.data.collate import PAD_SENTINEL, bucket_size
+from repro.data.voxelize import VoxelMap
+
+
+def _cloud(target):
+    """A target's points: a :class:`VoxelMap`'s, or the target itself."""
+    return target.points if isinstance(target, VoxelMap) else target
 
 
 def _mask_invalid(points: jax.Array, valid: jax.Array | None) -> jax.Array:
@@ -175,10 +183,15 @@ class RegistrationEngine:
         sentinel) pass masks directly; the clouds then go through at their
         given shapes, no re-bucketing. ``initial_transform`` is cast to
         f32 so a float64 warm start cannot poison the f32 trace.
+
+        ``target`` may be a :class:`~repro.data.voxelize.VoxelMap`, a
+        cloud on a voxel lattice: the slot engines search its lattice,
+        the others register onto its points as onto any cloud. The
+        correspondences within the gate are the same either way.
         """
         params = self._default_params(params)
         src = jnp.asarray(source, dtype=jnp.float32)
-        dst = jnp.asarray(target, dtype=jnp.float32)
+        dst = jnp.asarray(_cloud(target), dtype=jnp.float32)
         if initial_transform is not None:
             initial_transform = jnp.asarray(initial_transform, jnp.float32)
         if src_valid is not None or dst_valid is not None:
@@ -201,16 +214,20 @@ class RegistrationEngine:
                        initial_transforms=None) -> ICPResult:
         """Register a (B,N,3) source batch onto a (B,M,3) target batch in a
         single compiled program. Masks come from ``collate_pairs``; every
-        ``ICPResult`` leaf gains a leading batch axis."""
+        ``ICPResult`` leaf gains a leading batch axis. ``targets`` may be
+        a batch of voxel maps, as in :meth:`register`."""
         fn = self._executable("batch", self._default_params(params))
         if initial_transforms is not None:
             # f32 pin: a float64 warm-start batch must not poison the trace
             initial_transforms = jnp.asarray(initial_transforms, jnp.float32)
         return fn(jnp.asarray(sources, dtype=jnp.float32),
-                  jnp.asarray(targets, dtype=jnp.float32),
+                  self._batch_targets(targets),
                   initial_transforms,
                   None if src_valid is None else jnp.asarray(src_valid, bool),
                   None if dst_valid is None else jnp.asarray(dst_valid, bool))
+
+    def _batch_targets(self, targets):
+        return jnp.asarray(_cloud(targets), dtype=jnp.float32)
 
     def register_pairs(self, pairs, params: ICPParams | None = None,
                        initial_transforms=None):
@@ -440,6 +457,29 @@ class DistributedEngine(RegistrationEngine):
         return run  # batch_fn is already jitted
 
 
+def slot_lane(params: ICPParams, nn_fn: Callable | None):
+    """One lane of the slot executable, ``(src, dst, T0, sv, dv) ->
+    ICPResult``. A :class:`VoxelMap` target is searched through its
+    lattice table, built here, outside the ICP loop
+    (``core.nn_search_grid.lattice_nn_fn``), and the result carries the
+    table's ``lattice_overflow``; a plain cloud is searched with
+    ``nn_fn`` (None: the brute-force sweep)."""
+
+    def one(src, dst, T0, sv, dv):
+        if not isinstance(dst, VoxelMap):
+            return icp(src, dst, params, T0, nn_fn=nn_fn, src_valid=sv,
+                       dst_valid=dv)
+        points, dv = scrub_nonfinite(dst.points, dv)
+        lattice_fn, overflow = lattice_nn_fn(
+            dataclasses.replace(dst, points=points), dv,
+            params.max_correspondence_distance)
+        res = icp(src, points, params, T0, nn_fn=lattice_fn, src_valid=sv,
+                  dst_valid=dv)
+        return res._replace(lattice_overflow=overflow)
+
+    return one
+
+
 class SlotEngine(RegistrationEngine):
     """Fixed-width slot-batch engine backing the multi-stream registration
     service (DESIGN.md §13).
@@ -466,23 +506,27 @@ class SlotEngine(RegistrationEngine):
         self.slots = int(slots)
 
     def _build_batch(self, params: ICPParams):
-        nn_fn = self._nn_fn(params)
+        one = slot_lane(params, self._nn_fn(params))
 
         def run(src_b, dst_b, T0, sv, dv):
-            self._note_trace("batch", params, src_b.shape, dst_b.shape)
+            self._note_trace("batch", params, src_b.shape,
+                             _cloud(dst_b).shape)
             if T0 is None:
                 T0 = jnp.broadcast_to(jnp.eye(4, dtype=src_b.dtype),
                                       (src_b.shape[0], 4, 4))
-
-            def one(src, dst, T0_, sv_, dv_):
-                return icp(src, dst, params, T0_, nn_fn=nn_fn,
-                           src_valid=sv_, dst_valid=dv_)
-
             return jax.vmap(one)(src_b, dst_b, T0, sv, dv)
 
         # module name ``jit_run``: the name a trace reads the slot
         # executable by
         return jax.jit(run)
+
+    def _batch_targets(self, targets):
+        """Voxel-map targets stay maps: each lane searches its map's
+        lattice (:func:`slot_lane`), and the result's
+        ``lattice_overflow[s]`` counts lane s's map cells holding more
+        than ``max_per_cell`` points (0 keeps that search exact)."""
+        return jax.tree_util.tree_map(
+            lambda x: jnp.asarray(x, dtype=jnp.float32), targets)
 
     def register(self, source, target, params: ICPParams | None = None,
                  initial_transform=None, *, src_valid=None, dst_valid=None,
@@ -493,7 +537,10 @@ class SlotEngine(RegistrationEngine):
         after one iteration), and lane 0 of the batched result is
         returned. Same bucketing semantics as the base engine; crucially
         the executable is the SAME one the service's fleet step compiles,
-        so this path never adds a trace."""
+        so this path never adds a trace (for a :class:`VoxelMap` target,
+        the one the service's voxel-map batch compiles)."""
+        lattice = target if isinstance(target, VoxelMap) else None
+        target = _cloud(target)
         params = self._default_params(params)
         src = jnp.asarray(source, dtype=jnp.float32)
         dst = jnp.asarray(target, dtype=jnp.float32)
@@ -516,6 +563,11 @@ class SlotEngine(RegistrationEngine):
         sv_b = jnp.logical_and(lane[:, None], sv[None])
         dv_b = jnp.logical_and(lane[:, None], dv[None])
         T0_b = jnp.broadcast_to(T0[None], (self.slots, 4, 4))
+        if lattice is not None:
+            origin = jnp.asarray(lattice.origin, jnp.float32)
+            dst_b = dataclasses.replace(
+                lattice, points=dst_b,
+                origin=jnp.broadcast_to(origin, (self.slots, 3)))
         fn = self._executable("batch", params)
         res = fn(src_b, dst_b, T0_b, sv_b, dv_b)
         return jax.tree_util.tree_map(lambda x: x[0], res)
@@ -571,13 +623,14 @@ class ShardedSlotEngine(SlotEngine):
         mesh = self._mesh
 
         def run(src_b, dst_b, T0, sv, dv):
-            self._note_trace("batch", params, src_b.shape, dst_b.shape)
+            self._note_trace("batch", params, src_b.shape,
+                             _cloud(dst_b).shape)
             # shard_map's in_specs are fixed-arity: normalize the optional
             # masks/warm starts here (identical defaults to SlotEngine's).
             if sv is None:
                 sv = jnp.ones(src_b.shape[:2], bool)
             if dv is None:
-                dv = jnp.ones(dst_b.shape[:2], bool)
+                dv = jnp.ones(_cloud(dst_b).shape[:2], bool)
             return stream_sharded_icp(mesh, src_b, dst_b, params,
                                       initial_transforms=T0,
                                       src_valid=sv, dst_valid=dv,

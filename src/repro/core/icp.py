@@ -97,6 +97,9 @@ class ICPResult(NamedTuple):
     converged: jax.Array
     inlier_frac: jax.Array
     degenerate: jax.Array
+    # cells of a voxel-map target holding more points than its lattice
+    # search keeps a cell (``core.engine.slot_lane``); None elsewhere
+    lattice_overflow: jax.Array | None = None
 
 
 # Total weight below this is "no correspondence evidence at all": the

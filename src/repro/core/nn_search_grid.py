@@ -36,17 +36,29 @@ execute, so the pyramid engine relies on the gate semantics instead.
 Distances are computed directly as ``sum((p - q)²)`` — the candidate tile
 is too narrow for the matmul expansion to pay off, and the direct form is
 exact (no cancellation), so no epilogue recompute is needed.
+
+**Lattice search** (:func:`lattice_nn_fn`, the slot engines' searcher
+for a :class:`~repro.data.voxelize.VoxelMap` target). A voxel map's
+points already sit on the map's own lattice, at most ``max_per_cell`` a
+cell, so no grid is fitted to them: a column table of that lattice
+(:func:`lattice_table`) holds them, and a query sweeps the whole lattice
+columns within reach of the ICP gate (:func:`lattice_columns`), one
+lane-dense row gather a column. Every point within the gate is found
+with the brute-force sweep's ``d2`` unless a cell overflows, which the
+table counts.
 """
 from __future__ import annotations
 
 import functools
 import itertools
+import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.data.voxelize import VoxelGrid, cell_coords, linear_cell_ids
+from repro.data.voxelize import (VoxelGrid, VoxelMap, build_voxel_grid,
+                                 cell_coords, linear_cell_ids)
 
 
 class GridQueryStats(NamedTuple):
@@ -252,6 +264,127 @@ def nn_search_grid(src: jax.Array, grid: VoxelGrid, *,
     if with_stats:
         out.append(neighborhood_stats(src, grid, max_per_cell, rings))
     return tuple(out)
+
+
+@functools.lru_cache(maxsize=None)
+def lattice_columns(gate: float, voxel_size: float) -> tuple:
+    """The ``(dx, dy)`` offsets of the lattice columns that can hold a
+    point within ``gate`` of a query: those of the ``r = ceil(gate /
+    voxel_size)`` rings around the query's column whose nearest point
+    lies within the gate (an offset ``d`` leaves ``|d| - 1`` whole cells
+    between the columns). 21 at a 1.0 m gate on 0.75 m cells."""
+    rings = math.ceil(gate / voxel_size)
+    span = range(-rings, rings + 1)
+    return tuple(
+        d for d in itertools.product(span, span)
+        if sum((max(abs(a) - 1, 0) * voxel_size) ** 2 for a in d)
+        <= gate ** 2)
+
+
+def lattice_table(target: VoxelMap, valid: jax.Array | None):
+    """Lay a voxel map's valid points out by lattice column (once per
+    target, outside the ICP loop): row ``x·ny + y`` of the returned
+    ``(nx·ny + 1, 4·nz·K)`` table holds column ``(x, y)``'s ``nz·K``
+    slots, ``K = max_per_cell``, as four lane blocks: the slots' x, y and
+    z coordinates and the point's target row. Slot ``z·K + k`` is the
+    k-th point, in row order, of cell ``(x, y, z)``. Empty slots carry
+    ``_MASK_COORD`` coordinates and row -1, and the last row stays empty
+    for columns outside the lattice. A TPU gathers a lane-dense row
+    about as fast as a single element, so a search pays one gather a
+    column.
+
+    Returns ``(table, overflow)``: ``overflow`` counts the cells holding
+    more than K points, the only case in which :func:`nn_search_lattice`
+    can miss a neighbour (the extra points keep no slot)."""
+    points = target.points
+    (nx, ny, nz), k = target.dims, target.max_per_cell
+    slots = nz * k
+    grid = build_voxel_grid(points, target.voxel_size, target.dims,
+                            valid=valid, origin=target.origin)
+    ic = cell_coords(grid.points, grid.origin, grid.voxel_size, target.dims)
+    rank = (jnp.arange(points.shape[0], dtype=jnp.int32)
+            - grid.start[linear_cell_ids(ic, target.dims)])
+    kept = rank < k
+    if valid is not None:
+        kept = jnp.logical_and(kept, valid[grid.point_ids])
+    row = jnp.where(kept, ic[:, 0] * ny + ic[:, 1], nx * ny + 1)  # dropped
+    lane = (ic[:, 2] * k + rank)[:, None] + slots * jnp.arange(4)
+    values = jnp.concatenate(
+        [grid.points, grid.point_ids[:, None].astype(jnp.float32)], axis=1)
+    empty = jnp.repeat(jnp.asarray([_MASK_COORD] * 3 + [-1.0], jnp.float32),
+                       slots)
+    table = jnp.broadcast_to(empty, (nx * ny + 1, 4 * slots)).at[
+        row[:, None], lane].set(values, mode="drop")
+    return table, jnp.sum(grid.count > k).astype(jnp.int32)
+
+
+def nn_search_lattice(src: jax.Array, table: jax.Array, target: VoxelMap,
+                      columns: tuple):
+    """NN of each query among a :func:`lattice_table`'s points in the
+    lattice columns at ``columns`` from its own (:func:`lattice_columns`):
+    a column a step, one row gather and a dense sweep of its slots,
+    keeping the nearest so far (a tie keeps the earlier column). Cells
+    are found with ``cell_coords(..., clip=False)``, so a query outside
+    the lattice sees only the columns its window overlaps. Whole columns
+    hold cells beyond the gate too, which cannot displace a nearer
+    neighbour.
+
+    Returns ``(d2, idx, matched)`` with the ``nn_fn`` contract of
+    ``core.icp``, the winner's ``d2`` computed as the brute-force
+    searcher computes its own: a query with no candidate gets
+    ``d2 = +inf`` (no ICP gate passes it), index 0 and a far-sentinel
+    point."""
+    (nx, ny, _), slots = target.dims, table.shape[1] // 4
+    icq = cell_coords(src, target.origin, target.voxel_size, target.dims,
+                      clip=False)
+    q = src.astype(jnp.float32)
+
+    def column(best, offset):
+        # one column a step: only its gathered rows are live at a time
+        best_d2, best_hit = best
+        cx, cy = icq[:, 0] + offset[0], icq[:, 1] + offset[1]
+        inside = (cx >= 0) & (cx < nx) & (cy >= 0) & (cy < ny)
+        rows = table[jnp.where(inside, cx * ny + cy, nx * ny)]
+        block = [rows[:, a * slots:(a + 1) * slots] for a in range(4)]
+        ddx, ddy, ddz = (q[:, a, None] - block[a] for a in range(3))
+        d2 = ddx * ddx + ddy * ddy + ddz * ddz                # (N, slots)
+        col_d2 = jnp.min(d2, axis=1)
+        # the nearest slot's lanes by a one-hot sum: a TPU gathers single
+        # elements slowly, and x + 0 is exact
+        first = jnp.arange(slots) == jnp.argmin(d2, axis=1)[:, None]
+        hit = jnp.stack([jnp.sum(jnp.where(first, b, 0.0), axis=1)
+                         for b in block], axis=1)            # x, y, z, row
+        nearer = col_d2 < best_d2
+        return (jnp.where(nearer, col_d2, best_d2),
+                jnp.where(nearer[:, None], hit, best_hit)), None
+
+    init = (jnp.full(q.shape[:1], jnp.inf, jnp.float32),
+            jnp.broadcast_to(jnp.asarray([_MASK_COORD] * 3 + [-1.0],
+                                         jnp.float32), (q.shape[0], 4)))
+    (_, best), _ = jax.lax.scan(column, init, jnp.asarray(columns, jnp.int32))
+    row = best[:, 3].astype(jnp.int32)
+    found = row >= 0
+    matched = best[:, :3]
+    diff = q - matched
+    d2 = jnp.sum(diff * diff, axis=-1)
+    return (jnp.where(found, d2, jnp.inf), jnp.where(found, row, 0),
+            matched)
+
+
+def lattice_nn_fn(target: VoxelMap, dst_valid: jax.Array | None,
+                  gate: float):
+    """Resident-lattice searcher with the ``core.icp`` ``nn_fn`` contract
+    (the closure ignores its second argument), and the table's overflow
+    count. It finds every target point within ``gate`` of a query, with
+    the same ``d2`` as the brute-force sweep, wherever no cell overflows:
+    the gate is all ICP keeps of a correspondence."""
+    table, overflow = lattice_table(target, dst_valid)
+    columns = lattice_columns(float(gate), float(target.voxel_size))
+
+    def nn_fn(src, _target=None):
+        return nn_search_lattice(src, table, target, columns)
+
+    return nn_fn, overflow
 
 
 def grid_nn_fn(grid: VoxelGrid, *, max_per_cell: int = 32, rings: int = 1):

@@ -310,6 +310,11 @@ class OdometryPipeline:
             return self.poses[-1]
         return _compose(self.poses[-1], self._velocity)
 
+    def _voxel_map(self, map_pts):
+        """The primary registration's target: the submap's points on its
+        lattice, which a slot engine searches column by column."""
+        return self.submap.params.voxel_map(map_pts, self.submap.origin)
+
     # -- health ------------------------------------------------------------
     def _out_of_lattice_frac(self, res, src, sv) -> float:
         """Fraction of the (pose-transformed) scan outside the submap
@@ -402,8 +407,8 @@ class OdometryPipeline:
         if not (reacquire and cfg.recovery_tiers):
             res = primary
             if res is None:
-                res = self.engine.register(src, map_pts, cfg.params,
-                                           initial_transform=T0,
+                res = self.engine.register(src, self._voxel_map(map_pts),
+                                           cfg.params, initial_transform=T0,
                                            src_valid=sv, dst_valid=map_valid)
             health = self._assess(res, T0, src, sv, condition,
                                   out_of_lattice=out_of_lattice)
@@ -627,7 +632,8 @@ class OdometryPipeline:
         res = None
         if prep.kind == KIND_REGISTER and not prep.skip_primary:
             map_pts, map_valid = self.submap.target()
-            res = self.engine.register(prep.src, map_pts, self.config.params,
+            res = self.engine.register(prep.src, self._voxel_map(map_pts),
+                                       self.config.params,
                                        initial_transform=prep.T0,
                                        src_valid=prep.sv,
                                        dst_valid=map_valid)

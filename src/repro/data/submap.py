@@ -60,7 +60,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.data.collate import PAD_SENTINEL
-from repro.data.voxelize import VoxelGrid, build_voxel_grid, voxel_downsample
+from repro.data.voxelize import (VoxelGrid, VoxelMap, build_voxel_grid,
+                                 voxel_downsample)
 
 STORAGE_MODES = ("fp32", "fp16")
 
@@ -84,6 +85,19 @@ class SubmapParams(NamedTuple):
     dims: tuple[int, int, int] = (192, 192, 48)   # 96 m x 96 m x 24 m
     evict_radius: float = 45.0
     storage: str = "fp32"
+
+    def voxel_map(self, points, origin) -> VoxelMap:
+        """A map of these parameters as a registration target:
+        ``points`` on the lattice anchored at ``origin`` (``(M, 3)`` and
+        ``(3,)``, or with a leading lane axis). Every fuse keeps one
+        centroid a cell. The fp16 offsets can round a centroid onto its
+        cell's upper face, which is the next cell's: two points a cell
+        then, as the fleet scenes' maps show (three or more would take a
+        centroid within half an ulp of a cell edge)."""
+        return VoxelMap(points=points, origin=origin,
+                        voxel_size=float(self.voxel_size),
+                        dims=tuple(self.dims),
+                        max_per_cell=2 if self.storage == "fp16" else 1)
 
 
 # -- functional state API (fleet-batchable) ---------------------------------

@@ -79,6 +79,35 @@ class VoxelGrid:
         return nx * ny * nz
 
 
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass(frozen=True)
+class VoxelMap:
+    """A target cloud whose points sit on a voxel lattice: at most
+    ``max_per_cell`` of them in each cell of the ``dims`` lattice of
+    ``voxel_size`` cells anchored at ``origin`` (a map of
+    ``repro.data.submap`` holds one centroid a cell). The slot engines
+    search such a target through its lattice; other engines register onto
+    ``points`` as onto any cloud.
+
+    ``points`` ``(M, 3)`` and ``origin`` ``(3,)`` are the leaves, with a
+    leading lane axis in a batch; the rest is static (pytree aux data),
+    so a map crosses jit and vmap boundaries like :class:`VoxelGrid`."""
+
+    points: jax.Array
+    origin: jax.Array
+    voxel_size: float
+    dims: tuple[int, int, int]
+    max_per_cell: int
+
+    def tree_flatten(self):
+        return ((self.points, self.origin),
+                (self.voxel_size, self.dims, self.max_per_cell))
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(*leaves, *aux)
+
+
 def cell_coords(points: jax.Array, origin: jax.Array, voxel_size,
                 dims: tuple[int, int, int], *, clip: bool = True) -> jax.Array:
     """(…,3) points -> (…,3) int32 lattice coords.

@@ -370,6 +370,9 @@ class RegistrationService:
         self.lane_iterations = 0
         self.lane_iterations_run = 0
         self.loop_iterations = 0
+        # lattice-search counters (see ``service_report``)
+        self.lattice_searches = 0
+        self.lattice_overflow_cells = 0
         self._eye = np.eye(4, dtype=np.float32)
 
     @property
@@ -659,10 +662,11 @@ class RegistrationService:
 
     def _register(self, work: dict, preps: dict, reg_lanes: list, src_b,
                   sv_b):
-        """Register ``reg_lanes`` in one call of the slot executable, probe
-        the lattice for every lane, and fetch both to the host in one
-        ``device_get``; returns ``(res_host, lat_host)`` and adds the
-        round to the lane-iteration counters."""
+        """Register ``reg_lanes`` in one call of the slot executable, each
+        lane searching its map's lattice, probe the lattice for every
+        lane, and fetch both to the host in one ``device_get``; returns
+        ``(res_host, lat_host)`` and adds the round to the lane-iteration
+        and lattice counters."""
         odo = self.stream_config
         S = self.config.slots
         active = np.zeros((S,), bool)
@@ -692,7 +696,7 @@ class RegistrationService:
         T0_b = np.stack([preps[i].T0 if i in preps else self._eye
                          for i in range(S)])
         res = self.engine.register_batch(
-            src_b, dst_b, odo.params,
+            src_b, odo.submap.voxel_map(dst_b, origin_b), odo.params,
             src_valid=jnp.logical_and(sv_b, active_d[:, None]),
             dst_valid=jnp.logical_and(dv_b, active_d[:, None]),
             initial_transforms=T0_b)
@@ -705,6 +709,9 @@ class RegistrationService:
         with TraceAnnotation("fpps.wait.register"):
             res_host, lat_host = jax.device_get((res, lat_b))
         self._count_iterations(res_host.iterations, reg_lanes)
+        self.lattice_searches += len(reg_lanes)
+        self.lattice_overflow_cells += int(
+            res_host.lattice_overflow[reg_lanes].sum())
         return res_host, lat_host
 
     def _count_iterations(self, iterations: np.ndarray,
@@ -831,6 +838,12 @@ class RegistrationService:
             loop frozen until it ends);
           * ``loop_iterations`` — the rounds' trip counts (the slowest
             lane's iterations), summed;
+          * ``lattice_searches`` — registered lanes whose correspondences
+            came from their map's lattice table, summed over rounds;
+          * ``lattice_overflow_cells`` — map cells those lanes' tables
+            found holding more points than a cell slot keeps, summed: the
+            only way the lattice search can miss a neighbour inside the
+            gate, so 0 says the search was exact;
           * ``slots`` — the fleet width.
 
         ``1 - lane_iterations / lane_iterations_run`` is the share of the
@@ -849,6 +862,8 @@ class RegistrationService:
             "lane_iterations": self.lane_iterations,
             "lane_iterations_run": self.lane_iterations_run,
             "loop_iterations": self.loop_iterations,
+            "lattice_searches": self.lattice_searches,
+            "lattice_overflow_cells": self.lattice_overflow_cells,
             "slots": self.config.slots,
         }
 

@@ -97,7 +97,10 @@ def test_pallas_engine_batch_compiles(one_chip, fused):
 
 def test_slot_fleet_round_fits_v5e(one_chip):
     """The served 8-lane registration round at the ``OdometryConfig``
-    defaults (8192 scan points x 24576 map rows) fits one chip."""
+    defaults (8192 scan points x 24576 map rows) fits one chip, and so
+    does the lattice search at the fleet benchmark's widths: 32768 scan
+    points a lane against a 32768-row map on a (128, 128, 32) lattice,
+    in under half the sweep's temporaries there (2.31 GB)."""
     odo = OdometryConfig()
     n, m, s = odo.scan_budget, odo.submap.capacity, 8
     run = SlotEngine(slots=s)._build_batch(odo.params)
@@ -107,3 +110,14 @@ def test_slot_fleet_round_fits_v5e(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < V5E_HBM_BYTES, mem
+    n = m = 32768
+    sub = odo.submap._replace(capacity=m, dims=(128, 128, 32))
+    src, dst, T0, sv, dv, origin = (
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in (((s, n, 3), F32), ((s, m, 3), F32),
+                             ((s, 4, 4), F32), ((s, n), BOOL),
+                             ((s, m), BOOL), ((s, 3), F32)))
+    c = jax.jit(run).lower(src, sub.voxel_map(dst, origin), T0, sv,
+                           dv).compile()
+    mem = c.memory_analysis()
+    assert mem.temp_size_in_bytes < 2.31e9 / 2, mem

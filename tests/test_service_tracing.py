@@ -229,3 +229,51 @@ def test_registration_executables_trace_under_their_own_names(engine, kind,
     args = ((pts, pts, T0, mask, mask) if kind == "batch"
             else (pts[0], pts[0], T0[0], mask[0], mask[0]))
     assert _module_name(fn, *args) == name
+
+
+@pytest.mark.parametrize("engine", ["slots", "sharded-slots"])
+def test_slot_executable_on_a_lattice_keeps_its_name(engine):
+    """The lattice search builds its tables inside ``jit_run``, the module
+    ``icp_device_ms.fleet`` reads."""
+    rng = np.random.default_rng(0)
+    pts = jnp.asarray(rng.uniform(-5, 5, (2, 64, 3)), jnp.float32)
+    T0 = jnp.broadcast_to(jnp.eye(4, dtype=jnp.float32), (2, 4, 4))
+    mask = jnp.ones((2, 64), bool)
+    voxel_map = ODO.submap.voxel_map(pts, jnp.full((2, 3), -6.0))
+    fn = ENGINES[engine]()._executable("batch", PARAMS)
+    assert _module_name(fn, pts, voxel_map, T0, mask, mask) == "jit_run"
+
+
+@pytest.mark.parametrize("devices", [None, 1],
+                         ids=["single-device", "sharded"])
+def test_lattice_counters_count_the_registered_lanes(devices):
+    """Every frame the slot executable registers searched its map's
+    lattice, and no centroid map overflows a cell."""
+    svc, out = _drive(devices, _scans(garbage=False),
+                      odometry=ODO._replace(recovery=False))
+    rep = svc.service_report()
+    assert rep["lattice_searches"] == sum(
+        1 for frames in out.values() for _, d in frames if d.frame > 0)
+    assert rep["lattice_overflow_cells"] == 0
+
+
+def test_lattice_overflow_reads_a_planted_point():
+    """A second point planted in an occupied cell of a stream's map is
+    the one overflow the next round's search reports."""
+    odo = ODO._replace(recovery=False)
+    svc = RegistrationService(ServiceConfig(slots=SLOTS, scan_capacity=1024,
+                                            odometry=odo))
+    scans = sequence_scans(0, 3, SCENE)
+    svc.admit("veh0")
+    for f in range(2):
+        svc.submit("veh0", scans[f])
+        svc.step()
+    sub = svc._streams["veh0"].pipe.submap
+    points, valid, origin = sub.state
+    free = int(np.argmin(np.asarray(valid)))
+    sub.state = (points.at[free].set(points[0] + 0.01),
+                 valid.at[free].set(True), origin)
+    svc.submit("veh0", scans[2])
+    svc.step()
+    rep = svc.service_report()
+    assert (rep["lattice_searches"], rep["lattice_overflow_cells"]) == (2, 1)
