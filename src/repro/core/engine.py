@@ -17,7 +17,9 @@ residency) and ``core/distributed.py`` (fleet sharding):
     loop body (the paper's target-cloud-in-BRAM analogue);
   * **batched multi-frame ICP** — :meth:`RegistrationEngine.register_batch`
     runs a whole padded frame-pair batch as one device program via
-    ``core.icp.icp_batch``.
+    ``core.icp.icp_batch``; :meth:`RegistrationEngine.register_pairs`
+    collates and registers a list of pairs in ``fpps.pairs`` profiler
+    spans and counts the work in :meth:`RegistrationEngine.report`.
 
 Typical use::
 
@@ -36,6 +38,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.icp import (ICPParams, ICPResult, icp, icp_batch,
                             icp_fixed_iterations, scrub_nonfinite)
@@ -94,6 +97,11 @@ class RegistrationEngine:
         self._chunk = chunk
         self._cache: dict = {}     # (kind, ICPParams) -> jitted executable
         self._traces: list = []    # (kind, ICPParams, shapes) per (re)trace
+        # register_pairs counters (see ``report``)
+        self._pair_counts = dict.fromkeys(
+            ("pair_batches", "pairs", "pair_iterations_run",
+             "pair_iterations", "target_rows", "target_rows_padded"), 0)
+        self._pending_iterations: list = []  # device (B,) arrays, unread
 
     # -- introspection -----------------------------------------------------
     @property
@@ -234,15 +242,57 @@ class RegistrationEngine:
         """Collate variable-size [(src, dst), ...] and register as one batch.
 
         Returns (ICPResult, CollatedBatch) — the batch carries the true
-        per-frame sizes for unpadding downstream.
+        per-frame sizes for unpadding downstream. The call runs inside a
+        ``fpps.pairs`` profiler span (``batch=`` its ordinal, ``pairs=``
+        its size) holding ``fpps.pairs.collate`` and
+        ``fpps.pairs.dispatch``, and adds to :meth:`report`'s counters;
+        it waits for nothing on the device.
         """
         from repro.data.collate import collate_pairs
-        batch = collate_pairs(pairs)
-        res = self.register_batch(batch.src, batch.dst, params,
-                                  src_valid=batch.src_valid,
-                                  dst_valid=batch.dst_valid,
-                                  initial_transforms=initial_transforms)
+        counts = self._pair_counts
+        with TraceAnnotation("fpps.pairs", batch=counts["pair_batches"],
+                             pairs=len(pairs)):
+            with TraceAnnotation("fpps.pairs.collate"):
+                batch = collate_pairs(pairs)
+            with TraceAnnotation("fpps.pairs.dispatch"):
+                res = self.register_batch(
+                    batch.src, batch.dst, params,
+                    src_valid=batch.src_valid, dst_valid=batch.dst_valid,
+                    initial_transforms=initial_transforms)
+        b = len(batch.dst_sizes)
+        counts["pair_batches"] += 1
+        counts["pairs"] += b
+        counts["pair_iterations_run"] += (
+            b * self._default_params(params).max_iterations)
+        counts["target_rows"] += sum(batch.dst_sizes)
+        counts["target_rows_padded"] += b * batch.dst.shape[1]
+        self._pending_iterations.append(res.iterations)
         return res, batch
+
+    def report(self) -> dict:
+        """Counters of :meth:`register_pairs` since the engine was made:
+
+          * ``pair_batches``, ``pairs`` — calls and the pairs they held;
+          * ``pair_iterations_run`` — pairs x ``max_iterations``: the
+            pair-iterations the fixed-length batch scan computes, a
+            converged pair riding on frozen until the scan ends (the
+            slot engines' loop stops with its slowest pair: there it is
+            an upper bound);
+          * ``pair_iterations`` — the ICP iterations the pairs asked for
+            (``ICPResult.iterations``, summed), read from the device here
+            and not in ``register_pairs``, so this call waits for the
+            batches still running;
+          * ``target_rows``, ``target_rows_padded`` — the targets' points
+            and the rows the collated batches gave them (each batch pads
+            every target to the bucket of its largest).
+
+        ``1 - pair_iterations / pair_iterations_run`` is the share of the
+        batch executable's pair-iterations spent on converged pairs."""
+        counts = self._pair_counts
+        for its in self._pending_iterations:
+            counts["pair_iterations"] += int(np.asarray(its).sum())
+        self._pending_iterations.clear()
+        return dict(counts)
 
 
 class XLAEngine(RegistrationEngine):

@@ -17,6 +17,7 @@ from repro.core import ICPParams
 from repro.core.engine import PallasEngine, SlotEngine
 from repro.core.odometry import OdometryConfig
 from repro.kernels.fused_icp import fused_moment_sweep
+from repro.kernels.nn_search import KERNEL_NAME
 from repro.kernels.nn_search_grid import candidate_sweep_kernel
 from repro.kernels.normals import moment_sweep_kernel
 from repro.kernels.ops import nn_search_pallas
@@ -93,6 +94,23 @@ def test_pallas_engine_batch_compiles(one_chip, fused):
     c = _compile(run, one_chip, ((4, 4096, 3), F32), ((4, 32768, 3), F32),
                  None, ((4, 4096), BOOL), ((4, 32768), BOOL))
     assert "tpu_custom_call" in c.as_text()
+
+
+def test_pairs_batch_at_the_pair_cells_widths_fits_v5e(one_chip):
+    """The pallas engine's batch executable at the pair benchmark's
+    widths (8 pairs of 4096 sampled points onto targets padded to
+    262144 rows, its largest bucket, 50 iterations) fits one chip, and
+    its NN kernel's custom call carries the name a trace reads it by."""
+    params = ICPParams(max_iterations=50, max_correspondence_distance=1.0)
+    run = PallasEngine(interpret=False)._build_batch(params)
+    n, m = 4096, 262144
+    c = _compile(run, one_chip, ((8, n, 3), F32), ((8, m, 3), F32), None,
+                 ((8, n), BOOL), ((8, m), BOOL))
+    mem = c.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < V5E_HBM_BYTES, mem
+    assert f"%{KERNEL_NAME}." in c.as_text()
 
 
 def test_slot_fleet_round_fits_v5e(one_chip):
