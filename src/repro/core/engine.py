@@ -308,7 +308,7 @@ class PallasEngine(RegistrationEngine):
 
     The target augmentation is built once per frame at trace scope via
     ``kernels.ops.resident_nn_fn`` — each ICP iteration only augments the
-    small source cloud and runs the MXU kernel against the resident target.
+    small source cloud and runs the NN kernel against the resident target.
 
     ``params.fused`` swaps the whole iteration body for the single-pass
     moment kernel (``repro.kernels.fused_icp``, DESIGN.md §11): a resident
@@ -324,8 +324,8 @@ class PallasEngine(RegistrationEngine):
 
     name = "pallas"
 
-    def __init__(self, chunk: int = 2048, bn: int = 512, bm: int = 1024,
-                 interpret: bool | None = None,
+    def __init__(self, chunk: int = 2048, bn: int = 512,
+                 bm: int | None = None, interpret: bool | None = None,
                  grid_dims: tuple[int, int, int] = (128, 128, 32),
                  grid_voxel: float | None = None, max_per_cell: int = 128,
                  rings: int = 1, fused_bn: int | None = None,

@@ -15,13 +15,13 @@ import jax.numpy as jnp
 from repro.core.nn_search import closer_of_two
 from repro.kernels import ref
 from repro.kernels.common import round_up as _round_up
-from repro.kernels.nn_search import nn_search_kernel
+from repro.kernels.nn_search import nn_search_kernel, target_block
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bm", "interpret"))
 def nn_search_pallas(src: jax.Array, dst: jax.Array,
                      T: jax.Array | None = None,
-                     *, bn: int = 512, bm: int = 1024,
+                     *, bn: int = 512, bm: int | None = None,
                      interpret: bool | None = None):
     """NN of each (optionally T-transformed) src point in dst via the kernel.
 
@@ -31,6 +31,7 @@ def nn_search_pallas(src: jax.Array, dst: jax.Array,
     sliced off.
     """
     n, m = src.shape[0], dst.shape[0]
+    bm = target_block(interpret) if bm is None else bm
     n_pad, m_pad = _round_up(n, bn), _round_up(m, bm)
     src_aug = ref.augment_source(src, T, pad_to=n_pad)
     dst_aug = ref.augment_target(dst, pad_to=m_pad)
@@ -39,8 +40,8 @@ def nn_search_pallas(src: jax.Array, dst: jax.Array,
     return jnp.maximum(d2[:n], 0.0), idx[:n]
 
 
-def resident_nn_fn(dst: jax.Array, *, bn: int = 512, bm: int = 1024,
-                   interpret: bool | None = None):
+def resident_nn_fn(dst: jax.Array, *, bn: int = 512,
+                   bm: int | None = None, interpret: bool | None = None):
     """In-trace resident-target searcher for use *inside* a jitted program.
 
     Builds the (8, M') augmented target once at trace position — outside the
@@ -59,6 +60,7 @@ def resident_nn_fn(dst: jax.Array, *, bn: int = 512, bm: int = 1024,
     (as ``repro.data.collate`` produces) so they cannot win the argmin.
     """
     m = dst.shape[0]
+    bm = target_block(interpret) if bm is None else bm
     dst_aug = ref.augment_target(dst, pad_to=_round_up(m, bm))
 
     def nn_fn(src: jax.Array, _target=None):
@@ -72,8 +74,8 @@ def resident_nn_fn(dst: jax.Array, *, bn: int = 512, bm: int = 1024,
     return nn_fn
 
 
-def make_frame_engine(dst: jax.Array, *, bn: int = 512, bm: int = 1024,
-                      interpret: bool | None = None):
+def make_frame_engine(dst: jax.Array, *, bn: int = 512,
+                      bm: int | None = None, interpret: bool | None = None):
     """Pre-augment a target frame once; return nn_fn(src, T) for ICP loops.
 
     This is the intended production shape: the (8, M) augmented target is
@@ -81,6 +83,7 @@ def make_frame_engine(dst: jax.Array, *, bn: int = 512, bm: int = 1024,
     every ICP iteration.
     """
     m = dst.shape[0]
+    bm = target_block(interpret) if bm is None else bm
     m_pad = _round_up(m, bm)
     dst_aug = ref.augment_target(dst, pad_to=m_pad)
 

@@ -1,13 +1,16 @@
 """Pure-jnp oracles for the FPPS NN-search kernel.
 
-The kernel computes squared distances through an *augmented inner product*
-(see nn_search.py for the derivation):
+The kernel scores candidates through the augmented expansion (see
+nn_search.py for the derivation):
 
     score[i,j] = src_aug[:, i] · dst_aug[:, j] = ||R p_i + t - q_j||²
 
-The oracle builds the same augmented matrices and takes the full (N, M)
-product at once — no tiling, no running reduction — so any kernel bug in
-tiling/carry/index bookkeeping diverges from it.
+``nn_search_ref`` builds the same augmented matrices and takes the full
+(N, M) product at once — no tiling, no running reduction — so any kernel
+bug in tiling/carry/index bookkeeping diverges from it.
+``nn_search_ref_blocked`` replays the kernel's own elementwise arithmetic
+(``kernel_scores``) and tie order, so its indices match the kernel's
+exactly.
 """
 from __future__ import annotations
 
@@ -76,24 +79,26 @@ def nn_search_ref(src: jax.Array, dst: jax.Array,
     return jnp.maximum(d2, 0.0), idx
 
 
+@jax.jit
+def kernel_scores(src_aug: jax.Array, dst_aug: jax.Array) -> jax.Array:
+    """(N, M) scores as the kernel ranks them: ``‖q‖² - 2 p'·q``, the
+    expansion less the query's own ``‖p'‖²``, elementwise in float32 and
+    in the kernel's order of operations. Jitted, so that the CPU backend
+    contracts its multiply-adds as it does in the interpreted kernel."""
+    p = src_aug[:3, :, None]
+    t = dst_aug[:4, None, :]
+    return ((p[0] * t[0] + p[1] * t[1]) + p[2] * t[2]) + t[3]
+
+
 def nn_search_ref_blocked(src, dst, T, bn: int, bm: int):
-    """Oracle with the kernel's exact blocking/carry semantics (padding, block
-    argmin, strict-< cross-block update) but in pure jnp — isolates pure
-    Pallas issues (BlockSpec, revisiting, program_id) from math issues."""
+    """Oracle that replays the kernel's arithmetic (``kernel_scores``), its
+    padding to bn/bm and its tie order (the smallest index wins) in pure
+    jnp — isolates pure Pallas issues (BlockSpec, carries, program_id)
+    from math issues."""
     n, m = src.shape[0], dst.shape[0]
-    n_pad, m_pad = -n % bn, -m % bm
-    src_aug = augment_source(src, T, pad_to=n + n_pad)
-    dst_aug = augment_target(dst, pad_to=m + m_pad)
-    best_d2 = jnp.full((n + n_pad,), jnp.inf, jnp.float32)
-    best_idx = jnp.zeros((n + n_pad,), jnp.int32)
-    for j in range((m + m_pad) // bm):
-        dblk = dst_aug[:, j * bm:(j + 1) * bm]
-        scores = jax.lax.dot_general(src_aug, dblk, (((0,), (0,)), ((), ())),
-                                     precision=jax.lax.Precision.HIGHEST,
-                                     preferred_element_type=jnp.float32)
-        larg = jnp.argmin(scores, axis=1).astype(jnp.int32)
-        lmin = jnp.take_along_axis(scores, larg[:, None], 1)[:, 0]
-        upd = lmin < best_d2
-        best_d2 = jnp.where(upd, lmin, best_d2)
-        best_idx = jnp.where(upd, j * bm + larg, best_idx)
-    return jnp.maximum(best_d2[:n], 0.0), best_idx[:n]
+    src_aug = augment_source(src, T, pad_to=n + (-n % bn))
+    dst_aug = augment_target(dst, pad_to=m + (-m % bm))
+    scores = kernel_scores(src_aug, dst_aug)
+    idx = jnp.argmin(scores, axis=1).astype(jnp.int32)
+    best = jnp.take_along_axis(scores, idx[:, None], 1)[:, 0] + src_aug[4]
+    return jnp.maximum(best[:n], 0.0), idx[:n]

@@ -17,12 +17,16 @@ from repro.core import ICPParams
 from repro.core.engine import PallasEngine, SlotEngine
 from repro.core.odometry import OdometryConfig
 from repro.kernels.fused_icp import fused_moment_sweep
-from repro.kernels.nn_search import KERNEL_NAME
+from repro.kernels import nn_search
+from repro.kernels.nn_search import KERNEL_NAME, target_block, vmem_bytes
 from repro.kernels.nn_search_grid import candidate_sweep_kernel
 from repro.kernels.normals import moment_sweep_kernel
 from repro.kernels.ops import nn_search_pallas
 
 V5E_HBM_BYTES = 16 * 10**9
+# VMEM the chip's compiler keeps for itself in the NN kernel (80 KiB at
+# every tile size tried), on top of what ``vmem_bytes`` counts.
+COMPILER_VMEM = 128 * 2**10
 
 
 @pytest.fixture(scope="module")
@@ -60,6 +64,32 @@ def test_brute_nn_kernel_compiles(one_chip):
     c = _compile(lambda s, d: nn_search_pallas(s, d, interpret=False),
                  one_chip, ((4096, 3), F32), ((32768, 3), F32))
     assert "tpu_custom_call" in c.as_text()
+
+
+def test_nn_kernel_vmem_budget_matches_its_layout(one_chip, monkeypatch):
+    """``vmem_bytes`` counts what the NN kernel's layout takes at its
+    default tiles: the double-buffered blocks and the scratch. Held to
+    that count and the compiler's own room, the chip's compiler accepts
+    the kernel at the pair cell's widths; held to the count alone, it
+    refuses it."""
+    from jax.experimental.pallas import tpu as pltpu
+    budget = vmem_bytes(512, target_block(False))["total_double_buffered"]
+
+    def compile_within(limit):
+        def kwargs(interpret, semantics):
+            return {"interpret": False,
+                    "compiler_params": pltpu.CompilerParams(
+                        dimension_semantics=semantics,
+                        vmem_limit_bytes=limit)}
+        monkeypatch.setattr(nn_search, "pallas_call_kwargs", kwargs)
+        return _compile(lambda s, d: nn_search.nn_search_kernel(
+            s, d, interpret=False), one_chip, ((8, 4096), F32),
+            ((8, 262144), F32))
+
+    assert "tpu_custom_call" in compile_within(
+        budget + COMPILER_VMEM).as_text()
+    with pytest.raises(Exception, match="memory space vmem"):
+        compile_within(budget)
 
 
 def test_grid_sweep_kernel_compiles(one_chip):
@@ -100,7 +130,8 @@ def test_pairs_batch_at_the_pair_cells_widths_fits_v5e(one_chip):
     """The pallas engine's batch executable at the pair benchmark's
     widths (8 pairs of 4096 sampled points onto targets padded to
     262144 rows, its largest bucket, 50 iterations) fits one chip, and
-    its NN kernel's custom call carries the name a trace reads it by."""
+    its NN kernel compiles at the default tiles into one custom call
+    that carries the name a trace reads it by."""
     params = ICPParams(max_iterations=50, max_correspondence_distance=1.0)
     run = PallasEngine(interpret=False)._build_batch(params)
     n, m = 4096, 262144
@@ -110,7 +141,13 @@ def test_pairs_batch_at_the_pair_cells_widths_fits_v5e(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < V5E_HBM_BYTES, mem
-    assert f"%{KERNEL_NAME}." in c.as_text()
+    call = [line for line in c.as_text().splitlines()
+            if f"%{KERNEL_NAME}." in line and "custom-call" in line]
+    assert len(call) == 1, call
+    # The chip's default target block divides the bucket, so the resident
+    # target enters the kernel at its own width, 8 pairs of 8 rows.
+    assert m % target_block(False) == 0
+    assert f"f32[8,8,{m}]" in call[0], call[0][:400]
 
 
 def test_slot_fleet_round_fits_v5e(one_chip):
